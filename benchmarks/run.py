@@ -34,6 +34,8 @@ BENCHES = [
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     wanted = set(sys.argv[1:])
     failures = []
     for name, module in BENCHES:

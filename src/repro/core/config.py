@@ -126,7 +126,7 @@ LEGACY_KEYS = frozenset(
 
 def build_server_config(args, *, slo=None, est=None, obs=None,
                         faults=None, guard=None, tenancy=None,
-                        refit: Any = None) -> ServerConfig:
+                        refit: Any = None, dtype: Any = None) -> ServerConfig:
     """The one place launch/serve.py turns CLI flags into a ServerConfig.
 
     ``args`` is the serve argparse namespace; objects the launcher
@@ -134,7 +134,7 @@ def build_server_config(args, *, slo=None, est=None, obs=None,
     resilience seams, tenancy controller) are passed explicitly."""
     return ServerConfig(
         slo=slo, est=est,
-        max_slots=args.slots, max_len=args.max_len,
+        max_slots=args.slots, max_len=args.max_len, dtype=dtype,
         cache=CacheConfig(page_size=args.page_size,
                           share_prefix=args.share_prefix),
         execution=ExecConfig(partition=args.partition),

@@ -37,13 +37,15 @@ from typing import Callable, Deque, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core import analytics
 from repro.core.config import ServerConfig
 from repro.core.estimator import (CycleObservation, OnlineRefitter,
-                                  PerfEstimator, predict_cycle)
+                                  PerfEstimator, hardware_for,
+                                  predict_cycle)
 from repro.core.metadata import MetadataBuffer
 from repro.core.resource import ResourceManager
 from repro.core.scheduler import SchedulerConfig, SLOScheduler
@@ -51,6 +53,7 @@ from repro.kvcache.paged import PagedKVPool, transfer_pages
 from repro.launch.submesh import (HandoffPolicy, SubMeshSplit,
                                   carve_submeshes, chip_mesh, find_split)
 from repro.models import transformer as T
+from repro.models.attention import prefill_length
 from repro.obs import NULL_OBS, CycleEvent, Observability
 from repro.resilience.faults import (NULL_FAULTS, DispatchError, FaultInjector,
                                      HandoffError)
@@ -62,6 +65,26 @@ from repro.serving.request import Phase, Request, SLO
 # ---------------------------------------------------------------------------
 # jitted step functions (compiled once, reused — §3.4.2 pre-configured states)
 # ---------------------------------------------------------------------------
+
+def _mesh_jit(impl: Callable, sharding, *, donate_argnums=()) -> Callable:
+    """jit of the raw step ``impl``; keyword arguments (cfg, rep,
+    decode_share) are static. ``sharding`` names where the arguments live
+    replicated (chip-enabled serving keeps its state so, on a sub-mesh or
+    the global mesh; None = one device). XLA cannot partition a Pallas
+    kernel by itself, so on more than one device the whole step runs
+    under shard_map, every argument and result replicated: each device
+    computes the full step, exactly as the replicated program would."""
+    multi = sharding is not None and len(sharding.device_set) > 1
+
+    def run(*args, **static):
+        body = functools.partial(impl, **static)
+        if multi:
+            body = jax.shard_map(body, mesh=sharding.mesh, in_specs=P(),
+                                 out_specs=P(), check_vma=False)
+        return body(*args)
+    return jax.jit(run, static_argnames=("cfg", "rep", "decode_share"),
+                   donate_argnums=donate_argnums)
+
 
 @functools.partial(jax.jit, static_argnames=("cfg", "repeat"),
                    donate_argnums=(3,))
@@ -94,9 +117,8 @@ def _decode_iteration_impl(params, cache, tokens, pos, active,
     return next_tokens[:, None], cache
 
 
-_decode_iteration = functools.partial(
-    jax.jit, static_argnames=("cfg",), donate_argnums=(1,))(
-    _decode_iteration_impl)
+_decode_iteration = _mesh_jit(_decode_iteration_impl, None,
+                              donate_argnums=(1,))
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -137,15 +159,12 @@ def _prefill_group_paged_impl(params_slice, x, positions, *,
     return x, tuple(entries)
 
 
-_prefill_group_paged = functools.partial(
-    jax.jit, static_argnames=("cfg",))(_prefill_group_paged_impl)
+_prefill_group_paged = _mesh_jit(_prefill_group_paged_impl, None)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "rep", "decode_share"),
-                   donate_argnums=(1,))
-def _fused_step(params, cache, x, positions, page_map, tokens, pos, active,
-                block_tables, *, cfg: ModelConfig, rep: int,
-                decode_share: float):
+def _fused_step_impl(params, cache, x, positions, page_map, tokens, pos,
+                     active, block_tables, *, cfg: ModelConfig, rep: int,
+                     decode_share: float):
     """One spatially-fused engine cycle (§3.5 co-execution): pattern-repeat
     group ``rep`` of the in-flight prefill AND one continuous-batching
     decode iteration, in a single dispatch. At repeat ``rep`` each layer's
@@ -159,6 +178,9 @@ def _fused_step(params, cache, x, positions, page_map, tokens, pos, active,
     next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     next_tokens = jnp.where(active, next_tokens, 0)
     return x_p, next_tokens[:, None], cache
+
+
+_fused_step = _mesh_jit(_fused_step_impl, None, donate_argnums=(1,))
 
 
 class FusedExecutable(NamedTuple):
@@ -398,6 +420,12 @@ class BulletServer:
         self.cfg = cfg
         self.params = params
         self.slo = slo
+        devs = list(devices) if devices is not None else jax.devices()
+        if est is None and jax.default_backend() == "tpu":
+            # priced on the chips this server runs on: every device for
+            # the chip-granular partitions, the default one for tile
+            est = PerfEstimator(hardware_for(
+                devs if partition != "tile" else devs[:1]))
         self.est = est or PerfEstimator()
         self.buffer = MetadataBuffer()
         self.max_slots = max_slots
@@ -464,7 +492,6 @@ class BulletServer:
                 raise ValueError(
                     f"{cfg.name}: chip-granular partitions hand KV off "
                     "through the block-paged pool; needs paged=True")
-            devs = list(devices) if devices is not None else jax.devices()
             splits = carve_submeshes(devs) if paged else []
             if partition == "chip" and not splits:
                 raise ValueError(
@@ -578,6 +605,9 @@ class BulletServer:
             #: params replicated per sub-mesh, device_put lazily and cached
             #: by placement (each split reuses its sides' copies)
             self._mesh_params: Dict[object, object] = {}
+            #: shard_map'd steps for state replicated on several devices,
+            #: keyed by (raw step, placement)
+            self._mesh_steps: Dict[Tuple[Callable, object], Callable] = {}
             #: prefill-side staging page pool: chip tasks scatter prompt KV
             #: here (resident on the prefill sub-mesh); transfer_pages
             #: re-shards written pages into self.cache at migration
@@ -621,14 +651,30 @@ class BulletServer:
         activation shape, as for the tile half)."""
         split = find_split(splits, part.prefill_chips, part.decode_chips)
         assert split is not None, part
+        p_sharding = submesh_param_sharding(split.prefill_mesh)
+        d_sharding = submesh_cache_sharding(split.decode_mesh)
         return ChipExecutable(
-            part.config_id, split,
-            submesh_param_sharding(split.prefill_mesh),
-            submesh_cache_sharding(split.decode_mesh),
-            jax.jit(functools.partial(_prefill_group_paged_impl,
-                                      cfg=self.cfg)),
-            jax.jit(functools.partial(_decode_iteration_impl, cfg=self.cfg),
-                    donate_argnums=(1,)))
+            part.config_id, split, p_sharding, d_sharding,
+            functools.partial(_mesh_jit(_prefill_group_paged_impl,
+                                        p_sharding), cfg=self.cfg),
+            functools.partial(_mesh_jit(_decode_iteration_impl, d_sharding,
+                                        donate_argnums=(1,)),
+                              cfg=self.cfg))
+
+    def _placed(self, impl: Callable, sharding, module_jit: Callable,
+                donate_argnums=()) -> Callable:
+        """The step ``impl`` for state on ``sharding``: the module-level
+        jit ``module_jit`` unless chip-enabled serving placed the state on
+        several devices, then a cached :func:`_mesh_jit` of it."""
+        if (not self._chip_enabled or sharding is None
+                or len(sharding.device_set) == 1):
+            return module_jit
+        key = (impl, sharding)
+        fn = self._mesh_steps.get(key)
+        if fn is None:
+            fn = self._mesh_steps[key] = _mesh_jit(
+                impl, sharding, donate_argnums=donate_argnums)
+        return fn
 
     # -- sub-mesh placement (chip-enabled serving only) ------------------
     def _params_for(self, sharding):
@@ -926,7 +972,9 @@ class BulletServer:
         if share and batch_hit:
             self.ptask = self._build_shared_task(batch, lens)
         else:
-            plen = max(lens)
+            # on a TPU, padded to the kernels' block so prefill and the
+            # fused cycle run the Pallas kernels
+            plen = prefill_length(max(lens))
             toks = np.zeros((len(batch), plen), np.int32)
             for i, r in enumerate(batch):
                 toks[i, :lens[i]] = self._seq_tokens(r)
@@ -1085,8 +1133,10 @@ class BulletServer:
                 leaf["v"] = _scatter_suffix_group_pages(
                     leaf["v"], v_e, pm, off, rep_ix)
         elif self.paged:
-            task.x, kv_entries = _prefill_group_paged(
-                p_slice, task.x, task.positions, cfg=self.cfg)
+            step = self._placed(_prefill_group_paged_impl, task.sharding,
+                                _prefill_group_paged)
+            task.x, kv_entries = step(p_slice, task.x, task.positions,
+                                      cfg=self.cfg)
             pm = task.page_map
             rep_ix = jnp.int32(rep)
             for j, (k_e, v_e) in enumerate(kv_entries):
@@ -1486,7 +1536,10 @@ class BulletServer:
             n_b = self._decode_block_bucket(ctxs_ran)
             streamed = (n_b * self.page_size * self.max_slots
                         // max(n_ran, 1),) * n_ran
-            next_tokens, self.cache = _decode_iteration(
+            step = self._placed(_decode_iteration_impl,
+                                self._decode_sharding, _decode_iteration,
+                                donate_argnums=(1,))
+            next_tokens, self.cache = step(
                 params, self.cache, self.tokens, self.pos, self.active,
                 self._device_tables(n_b), cfg=self.cfg)
         else:
@@ -1573,7 +1626,14 @@ class BulletServer:
         n_b = self._decode_block_bucket(ctxs_ran)
         streamed = (n_b * self.page_size * self.max_slots
                     // max(n_ran, 1),) * n_ran
-        task.x, next_tokens, self.cache = ex.fn(
+        fn = ex.fn
+        if self._chip_enabled:
+            # a chip-enabled server's tile cycles run on the global mesh
+            fn = functools.partial(
+                self._placed(_fused_step_impl, self._global_sharding,
+                             _fused_step, donate_argnums=(1,)),
+                cfg=self.cfg, decode_share=round(ex.decode_share, 6))
+        task.x, next_tokens, self.cache = fn(
             params, self.cache, task.x, task.positions,
             task.page_map, self.tokens, self.pos, self.active,
             self._device_tables(n_b), rep=task.rep)

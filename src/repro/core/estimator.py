@@ -85,6 +85,29 @@ A100_LIKE = HardwareSpec(name="a100-80g", n_chips=1, peak_flops=312e12,
                          hbm_bw=2.0e12, units_per_chip=108, grid_slots=108)
 TPU_V5E = HardwareSpec()
 
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``. TPU v5e
+#: ("TPU v5 lite"): Google Cloud documentation, "TPU v5e" — 197 TFLOP/s
+#: bf16, 819 GB/s and 16 GB of HBM per chip.
+DEVICE_PEAKS = {
+    "TPU v5 lite": dict(name="tpu-v5e", peak_flops=197e12, hbm_bw=819e9),
+}
+
+
+def hardware_for(devices) -> HardwareSpec:
+    """The spec of the devices a server actually runs on: peaks from
+    ``DEVICE_PEAKS`` by ``device_kind``, one chip per device. A kind that
+    is not in the table is an error, never a default."""
+    kinds = {d.device_kind for d in devices}
+    if len(kinds) != 1:
+        raise ValueError(f"expected one device kind, got {sorted(kinds)}")
+    kind = kinds.pop()
+    if kind not in DEVICE_PEAKS:
+        raise ValueError(f"no published peaks for device kind {kind!r}; "
+                         "add it to DEVICE_PEAKS with its source")
+    peaks = dict(DEVICE_PEAKS[kind])
+    name = f"{peaks.pop('name')}-{len(devices)}"
+    return HardwareSpec(name=name, n_chips=len(devices), **peaks)
+
 
 def wave_quantization_idle(grid: int, slots: int) -> float:
     """Eq. 1: idle fraction caused by the tail wave."""

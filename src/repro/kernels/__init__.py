@@ -3,11 +3,6 @@ the fused prefill+decode co-execution schedule, plus the recurrent scans
 the SSM/hybrid assigned architectures need. Validated against ref.py
 oracles in interpret mode (tests/test_kernels.py)."""
 
-from jax.experimental.pallas import tpu as _pltpu
-
-if not hasattr(_pltpu, "CompilerParams"):       # jax < 0.5 naming
-    _pltpu.CompilerParams = _pltpu.TPUCompilerParams
-
 from repro.kernels.ops import (
     flash_attention_op,
     decode_attention_op,

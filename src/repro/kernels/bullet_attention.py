@@ -33,7 +33,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from repro.kernels.decode_attention import (attend_tile, finish_softmax,
+                                            flat_tile, gqa_tile_masks,
+                                            init_softmax)
 
 
 def build_schedule(n_prefill: int, n_decode: int, decode_share: float
@@ -86,95 +88,84 @@ def _mk_index_arrays(phase: np.ndarray, dims_p: Tuple[int, ...],
     return p_idx, d_idx
 
 
-def _bullet_kernel(phase_ref, pbh_ref, pqi_ref, pki_ref,
-                   db_ref, dh_ref, dsi_ref, pos_ref,
-                   qp_ref, kp_ref, vp_ref,
-                   qd_ref, kd_ref, vd_ref, kvpos_ref,
-                   op_ref, od_ref,
-                   pm, plse, pacc, dm, dlse, dacc, *,
-                   bq, bk, bs, n_kv_p, n_s_d, causal, window,
-                   scale_p, scale_d):
+def _prefill_tile(qp_ref, kp_ref, vp_ref, m_ref, l_ref, acc_ref, qi, ki, *,
+                  bq, bk, causal, window, scale):
+    """One (q tile, kv tile) flash-attention step of the prefill stream."""
+    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    mask = jnp.ones((bq, bk), bool)
+    if causal:
+        mask = mask & (k_pos <= q_pos)
+    if window > 0:
+        mask = mask & (k_pos > q_pos - window)
+    attend_tile(qp_ref[0].astype(jnp.float32) * scale,
+                kp_ref[0].astype(jnp.float32), vp_ref[0].astype(jnp.float32),
+                mask, m_ref, l_ref, acc_ref)
+
+
+def _fused_kernel(phase_ref, pqi_ref, pki_ref, dsi_ref, qp_ref, kp_ref,
+                  vp_ref, op_ref, od_ref, pm, plse, pacc, dm, dlse, dacc,
+                  decode_valid, qd_ref, kd_ref, vd_ref, *, bq, bk, n_kv_p,
+                  n_s_d, causal, window, scale):
+    """The body both fused kernels share: a prefill flash tile or a decode
+    tile, by the step's phase. ``decode_valid`` gives the decode tile's
+    (H, n·K) mask; it is only evaluated on decode steps."""
     g = pl.program_id(0)
     ph = phase_ref[g]
     ki = pki_ref[g]
-    qi = pqi_ref[g]
     si = dsi_ref[g]
 
     # ---------------- prefill tile (compute-bound) ----------------
     @pl.when((ph == 0) & (ki == 0))
     def _init_p():
-        pm[...] = jnp.full_like(pm, NEG_INF)
-        plse[...] = jnp.zeros_like(plse)
-        pacc[...] = jnp.zeros_like(pacc)
+        init_softmax(pm, plse, pacc)
 
     @pl.when(ph == 0)
     def _prefill():
-        q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        q = qp_ref[0].astype(jnp.float32) * scale_p
-        k = kp_ref[0].astype(jnp.float32)
-        logits = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-        mask = jnp.ones((bq, bk), bool)
-        if causal:
-            mask = mask & (k_pos <= q_pos)
-        if window > 0:
-            mask = mask & (k_pos > q_pos - window)
-        logits = jnp.where(mask, logits, NEG_INF)
-        m_new = jnp.maximum(pm[...], logits.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(pm[...] - m_new)
-        p = jnp.where(mask, jnp.exp(logits - m_new), 0.0)
-        plse[...] = plse[...] * alpha + p.sum(axis=-1, keepdims=True)
-        pacc[...] = pacc[...] * alpha + jax.lax.dot_general(
-            p, vp_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        pm[...] = m_new
+        _prefill_tile(qp_ref, kp_ref, vp_ref, pm, plse, pacc, pqi_ref[g], ki,
+                      bq=bq, bk=bk, causal=causal, window=window,
+                      scale=scale)
 
     @pl.when((ph == 0) & (ki == n_kv_p - 1))
     def _fin_p():
-        op_ref[0] = (pacc[...] /
-                     jnp.maximum(plse[...], 1e-30)).astype(op_ref.dtype)
+        op_ref[0] = finish_softmax(plse, pacc, op_ref.dtype)
 
     # ---------------- decode tile (memory-bound) -------------------
     @pl.when((ph == 1) & (si == 0))
     def _init_d():
-        dm[...] = jnp.full_like(dm, NEG_INF)
-        dlse[...] = jnp.zeros_like(dlse)
-        dacc[...] = jnp.zeros_like(dacc)
+        init_softmax(dm, dlse, dacc)
 
     @pl.when(ph == 1)
     def _decode():
-        q = qd_ref[0, 0].astype(jnp.float32) * scale_d       # (G, D)
-        k = kd_ref[0, :, 0].astype(jnp.float32)              # (bs, D)
-        logits = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-        kvpos = kvpos_ref[0]
-        pos = pos_ref[db_ref[g]]
-        valid = (kvpos >= 0) & (kvpos <= pos)
-        logits = jnp.where(valid[None, :], logits, NEG_INF)
-        m_new = jnp.maximum(dm[...], logits.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(dm[...] - m_new)
-        p = jnp.where(valid[None, :], jnp.exp(logits - m_new), 0.0)
-        dlse[...] = dlse[...] * alpha + p.sum(axis=-1, keepdims=True)
-        dacc[...] = dacc[...] * alpha + jax.lax.dot_general(
-            p, vd_ref[0, :, 0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dm[...] = m_new
+        attend_tile(qd_ref[0].astype(jnp.float32) * scale, flat_tile(kd_ref),
+                    flat_tile(vd_ref), decode_valid(g, si), dm, dlse, dacc)
 
     @pl.when((ph == 1) & (si == n_s_d - 1))
     def _fin_d():
-        od_ref[0, 0] = (dacc[...] /
-                        jnp.maximum(dlse[...], 1e-30)).astype(od_ref.dtype)
+        od_ref[0] = finish_softmax(dlse, dacc, od_ref.dtype)
 
 
-def _bullet_paged_kernel(phase_ref, pbh_ref, pqi_ref, pki_ref,
-                         db_ref, dh_ref, dsi_ref, pos_ref, bt_ref,
-                         qp_ref, kp_ref, vp_ref,
-                         qd_ref, kpg_ref, vpg_ref,
-                         op_ref, od_ref,
-                         pm, plse, pacc, dm, dlse, dacc, *,
-                         bq, bk, ps, n_kv_p, n_b, causal, window,
-                         scale_p, scale_d):
+def _bullet_kernel(phase_ref, pbh_ref, pqi_ref, pki_ref, db_ref, dsi_ref,
+                   pos_ref, qp_ref, kp_ref, vp_ref, qd_ref, kd_ref, vd_ref,
+                   kvpos_ref, same_ref, op_ref, od_ref, *scratch, **kw):
+    """Fused schedule over prefill tiles and dense-cache decode tiles
+    (masking by the table-driven ``kv_positions``)."""
+    del pbh_ref                      # consumed by the index maps
+
+    def decode_valid(g, si):
+        kvpos = kvpos_ref[0]                                 # (1, bs·K)
+        return ((same_ref[...] == 1) & (kvpos >= 0)
+                & (kvpos <= pos_ref[db_ref[g]]))
+
+    _fused_kernel(phase_ref, pqi_ref, pki_ref, dsi_ref, qp_ref, kp_ref,
+                  vp_ref, op_ref, od_ref, *scratch, decode_valid, qd_ref,
+                  kd_ref, vd_ref, **kw)
+
+
+def _bullet_paged_kernel(phase_ref, pbh_ref, pqi_ref, pki_ref, db_ref,
+                         dsi_ref, pos_ref, bt_ref, qp_ref, kp_ref, vp_ref,
+                         qd_ref, kpg_ref, vpg_ref, same_ref, tok_ref,
+                         op_ref, od_ref, *scratch, ps, **kw):
     """Fused schedule over prefill tiles and *paged* decode tiles.
 
     Identical to ``_bullet_kernel`` on the prefill side; the decode side
@@ -183,78 +174,78 @@ def _bullet_paged_kernel(phase_ref, pbh_ref, pqi_ref, pki_ref,
     ``[col·ps, (col+1)·ps)``), so masking is positional like
     ``paged_decode_attention`` instead of table-driven ``kv_positions``.
     """
-    del bt_ref                       # consumed by the index maps
-    g = pl.program_id(0)
-    ph = phase_ref[g]
-    ki = pki_ref[g]
-    qi = pqi_ref[g]
-    si = dsi_ref[g]
+    del pbh_ref, bt_ref              # consumed by the index maps
 
-    # ---------------- prefill tile (compute-bound) ----------------
-    @pl.when((ph == 0) & (ki == 0))
-    def _init_p():
-        pm[...] = jnp.full_like(pm, NEG_INF)
-        plse[...] = jnp.zeros_like(plse)
-        pacc[...] = jnp.zeros_like(pacc)
+    def decode_valid(g, si):
+        kvpos = si * ps + tok_ref[...]                       # (1, ps·K)
+        return (same_ref[...] == 1) & (kvpos <= pos_ref[db_ref[g]])
 
-    @pl.when(ph == 0)
-    def _prefill():
-        q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        q = qp_ref[0].astype(jnp.float32) * scale_p
-        k = kp_ref[0].astype(jnp.float32)
-        logits = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-        mask = jnp.ones((bq, bk), bool)
-        if causal:
-            mask = mask & (k_pos <= q_pos)
-        if window > 0:
-            mask = mask & (k_pos > q_pos - window)
-        logits = jnp.where(mask, logits, NEG_INF)
-        m_new = jnp.maximum(pm[...], logits.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(pm[...] - m_new)
-        p = jnp.where(mask, jnp.exp(logits - m_new), 0.0)
-        plse[...] = plse[...] * alpha + p.sum(axis=-1, keepdims=True)
-        pacc[...] = pacc[...] * alpha + jax.lax.dot_general(
-            p, vp_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        pm[...] = m_new
+    _fused_kernel(phase_ref, pqi_ref, pki_ref, dsi_ref, qp_ref, kp_ref,
+                  vp_ref, op_ref, od_ref, *scratch, decode_valid, qd_ref,
+                  kpg_ref, vpg_ref, **kw)
 
-    @pl.when((ph == 0) & (ki == n_kv_p - 1))
-    def _fin_p():
-        op_ref[0] = (pacc[...] /
-                     jnp.maximum(plse[...], 1e-30)).astype(op_ref.dtype)
 
-    # ---------------- decode tile (one KV page, memory-bound) ------
-    @pl.when((ph == 1) & (si == 0))
-    def _init_d():
-        dm[...] = jnp.full_like(dm, NEG_INF)
-        dlse[...] = jnp.zeros_like(dlse)
-        dacc[...] = jnp.zeros_like(dacc)
+def _fused_call(kernel, prefetch, qp, kp, vp, qd, decode_specs,
+                decode_inputs, *, n_d_tiles, dims_d, decode_share, bq, bk,
+                group, interpret):
+    """Build the interleaved schedule and launch one fused ``pallas_call``.
 
-    @pl.when(ph == 1)
-    def _decode():
-        q = qd_ref[0, 0].astype(jnp.float32) * scale_d       # (G, D)
-        k = kpg_ref[0, :, 0].astype(jnp.float32)             # (ps, D)
-        logits = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-        kvpos = si * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-        pos = pos_ref[db_ref[g]]
-        valid = kvpos <= pos                                 # (1, ps)
-        logits = jnp.where(valid, logits, NEG_INF)
-        m_new = jnp.maximum(dm[...], logits.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(dm[...] - m_new)
-        p = jnp.where(valid, jnp.exp(logits - m_new), 0.0)
-        dlse[...] = dlse[...] * alpha + p.sum(axis=-1, keepdims=True)
-        dacc[...] = dacc[...] * alpha + jax.lax.dot_general(
-            p, vpg_ref[0, :, 0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dm[...] = m_new
+    ``prefetch`` are the scalar-prefetch operands that follow the schedule
+    arrays (pos, and the block tables for the paged kernel);
+    ``decode_specs(ix)`` builds the decode-side input BlockSpecs from
+    ``ix(f)``, which turns ``f(db, dsi, *prefetch)`` into an index map."""
+    bhp, sp, d = qp.shape
+    bd, h, _ = qd.shape
+    n_q, n_kv = sp // bq, sp // bk
+    dims_p = (bhp, n_q, n_kv)
+    phase = build_schedule(int(np.prod(dims_p)), n_d_tiles, decode_share)
+    p_idx, d_idx = _mk_index_arrays(phase, dims_p, dims_d)
+    # phase, pbh, pqi, pki, db, dsi. On the chip the pipeline evaluates
+    # the index maps one step past the grid to prefetch; without trailing
+    # entries that read runs off the arrays and the block-table lookup
+    # turns it into an out-of-bounds page DMA, which halts the core.
+    # Hold-last padding (to a multiple of 128) keeps every read in bounds.
+    n = len(phase)
+    pad = -(-(n + 1) // 128) * 128 - n
+    sched = [np.concatenate([a, np.repeat(a[-1:], pad)])
+             for a in (phase, *p_idx, *d_idx)]
 
-    @pl.when((ph == 1) & (si == n_b - 1))
-    def _fin_d():
-        od_ref[0, 0] = (dacc[...] /
-                        jnp.maximum(dlse[...], 1e-30)).astype(od_ref.dtype)
+    def ix(f):
+        return lambda g, ph, pbh, pqi, pki, db, dsi, *pre: f(
+            g, pbh, pqi, pki, db, dsi, *pre)
+
+    q_spec = pl.BlockSpec((1, bq, d), ix(
+        lambda g, pbh, pqi, pki, db, dsi, *pre: (pbh[g], pqi[g], 0)))
+    kv_spec = pl.BlockSpec((1, bk, d), ix(
+        lambda g, pbh, pqi, pki, db, dsi, *pre: (pbh[g] // group, pki[g], 0)))
+    qd_spec = pl.BlockSpec((1, h, d), ix(
+        lambda g, pbh, pqi, pki, db, dsi, *pre: (db[g], 0, 0)))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(sched) + len(prefetch),
+            grid=(n,),
+            in_specs=[q_spec, kv_spec, kv_spec, qd_spec,
+                      *decode_specs(ix)],
+            out_specs=[q_spec, qd_spec],
+            scratch_shapes=[
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, d), jnp.float32),
+                pltpu.VMEM((h, 1), jnp.float32),
+                pltpu.VMEM((h, 1), jnp.float32),
+                pltpu.VMEM((h, d), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((bhp, sp, d), qp.dtype),
+            jax.ShapeDtypeStruct((bd, h, d), qd.dtype),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(*[jnp.asarray(a) for a in sched], *prefetch, qp, kp, vp, qd,
+      *decode_inputs)
 
 
 def bullet_attention_paged(qp, kp, vp, qd, k_pages, v_pages, block_tables,
@@ -271,98 +262,47 @@ def bullet_attention_paged(qp, kp, vp, qd, k_pages, v_pages, block_tables,
              live context), pos (Bd,) absolute position of the new token.
     Returns (out_p (BHp, Sp, D), out_d (Bd, K, G, D)).
 
-    The decode tile stream walks ``(slot, kv_head, block)``; each tile's
-    page index comes from the scalar-prefetched block table, so — like
-    ``paged_decode_attention`` — only pages the tables name are ever
-    DMA'd, while the Bresenham schedule still hides that HBM traffic under
-    the prefill tiles' MXU work.
+    The decode tile stream walks ``(slot, block)``, one whole page (all KV
+    heads) per tile; each tile's page index comes from the
+    scalar-prefetched block table, so — like ``paged_decode_attention`` —
+    only pages the tables name are ever DMA'd, while the Bresenham
+    schedule still hides that HBM traffic under the prefill tiles' MXU
+    work.
     """
-    bhp, sp, d = qp.shape
+    sp, d = qp.shape[1:]
     bd, kh, gg, _ = qd.shape
+    h = kh * gg
     ps = k_pages.shape[1]
     n_b = block_tables.shape[1]
     bq, bk = min(block_q, sp), min(block_k, sp)
     assert sp % bq == 0 and sp % bk == 0
-    n_q, n_kv = sp // bq, sp // bk
+    same, tok = gqa_tile_masks(h, kh, ps)
 
-    dims_p = (bhp, n_q, n_kv)
-    dims_d = (bd, kh, n_b)
-    n_p_tiles = int(np.prod(dims_p))
-    n_d_tiles = int(np.prod(dims_d))
-    phase = build_schedule(n_p_tiles, n_d_tiles, decode_share)
-    p_idx, d_idx = _mk_index_arrays(phase, dims_p, dims_d)
-    pbh, pqi, pki = p_idx
-    db, dh, dsi = d_idx
+    def decode_specs(ix):
+        page = pl.BlockSpec((1, ps, kh, d), ix(
+            lambda g, pbh, pqi, pki, db, dsi, pos, bt:
+            (bt[db[g], dsi[g]], 0, 0, 0)))
+        const = ix(lambda *_: (0, 0))
+        return [page, page, pl.BlockSpec((h, ps * kh), const),
+                pl.BlockSpec((1, ps * kh), const)]
 
     kernel = functools.partial(
-        _bullet_paged_kernel,
-        bq=bq, bk=bk, ps=ps, n_kv_p=n_kv, n_b=n_b,
-        causal=causal, window=window,
-        scale_p=d ** -0.5, scale_d=d ** -0.5)
-
-    # Schedule arrays + pos + block tables ride in as scalar prefetch so
-    # the decode index maps can turn (slot, block) into a physical page.
-    out_p, out_d = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=9,
-            grid=(len(phase),),
-            in_specs=[
-                pl.BlockSpec((1, bq, d),
-                             lambda g, ph, pbh, pqi, pki, db, dh, dsi, pos,
-                             bt: (pbh[g], pqi[g], 0)),
-                pl.BlockSpec((1, bk, d),
-                             lambda g, ph, pbh, pqi, pki, db, dh, dsi, pos,
-                             bt: (pbh[g] // group, pki[g], 0)),
-                pl.BlockSpec((1, bk, d),
-                             lambda g, ph, pbh, pqi, pki, db, dh, dsi, pos,
-                             bt: (pbh[g] // group, pki[g], 0)),
-                pl.BlockSpec((1, 1, gg, d),
-                             lambda g, ph, pbh, pqi, pki, db, dh, dsi, pos,
-                             bt: (db[g], dh[g], 0, 0)),
-                pl.BlockSpec((1, ps, 1, d),
-                             lambda g, ph, pbh, pqi, pki, db, dh, dsi, pos,
-                             bt: (bt[db[g], dsi[g]], 0, dh[g], 0)),
-                pl.BlockSpec((1, ps, 1, d),
-                             lambda g, ph, pbh, pqi, pki, db, dh, dsi, pos,
-                             bt: (bt[db[g], dsi[g]], 0, dh[g], 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, bq, d),
-                             lambda g, ph, pbh, pqi, pki, db, dh, dsi, pos,
-                             bt: (pbh[g], pqi[g], 0)),
-                pl.BlockSpec((1, 1, gg, d),
-                             lambda g, ph, pbh, pqi, pki, db, dh, dsi, pos,
-                             bt: (db[g], dh[g], 0, 0)),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((bq, 1), jnp.float32),
-                pltpu.VMEM((bq, 1), jnp.float32),
-                pltpu.VMEM((bq, d), jnp.float32),
-                pltpu.VMEM((gg, 1), jnp.float32),
-                pltpu.VMEM((gg, 1), jnp.float32),
-                pltpu.VMEM((gg, d), jnp.float32),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((bhp, sp, d), qp.dtype),
-            jax.ShapeDtypeStruct((bd, kh, gg, d), qd.dtype),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(jnp.asarray(phase), jnp.asarray(pbh), jnp.asarray(pqi),
-      jnp.asarray(pki), jnp.asarray(db), jnp.asarray(dh), jnp.asarray(dsi),
-      pos.astype(jnp.int32), block_tables.astype(jnp.int32),
-      qp, kp, vp, qd, k_pages, v_pages)
-    return out_p, out_d
+        _bullet_paged_kernel, ps=ps, bq=bq, bk=bk, n_kv_p=sp // bk,
+        n_s_d=n_b, causal=causal, window=window, scale=d ** -0.5)
+    out_p, out_d = _fused_call(
+        kernel, [pos.astype(jnp.int32), block_tables.astype(jnp.int32)],
+        qp, kp, vp, qd.reshape(bd, h, d), decode_specs,
+        [k_pages, v_pages, same, tok], n_d_tiles=bd * n_b, dims_d=(bd, n_b),
+        decode_share=decode_share, bq=bq, bk=bk, group=group,
+        interpret=interpret)
+    return out_p, out_d.reshape(bd, kh, gg, d)
 
 
 def bullet_attention(qp, kp, vp, qd, kd, vd, kv_positions, pos, *,
                      decode_share: float = 0.5,
                      causal: bool = True, window: int = 0,
                      block_q: int = 128, block_k: int = 128,
-                     block_s: int = 512, group: int = 1,
+                     block_s: int = 128, group: int = 1,
                      interpret: bool = False):
     """Fused prefill+decode attention.
 
@@ -371,86 +311,34 @@ def bullet_attention(qp, kp, vp, qd, kd, vd, kv_positions, pos, *,
              pos (Bd,).
     Returns (out_p (BHp, Sp, D), out_d (Bd, K, G, D)).
     """
-    bhp, sp, d = qp.shape
+    sp, d = qp.shape[1:]
     bd, kh, gg, _ = qd.shape
+    h = kh * gg
     sk = kd.shape[1]
     bq, bk = min(block_q, sp), min(block_k, sp)
     bs = min(block_s, sk)
     assert sp % bq == 0 and sp % bk == 0 and sk % bs == 0
-    n_q, n_kv = sp // bq, sp // bk
     n_s = sk // bs
+    # one position per flattened (token, kv head) column
+    kvpos_cols = jnp.repeat(kv_positions.astype(jnp.int32), kh,
+                            axis=1)[:, None, :]            # (Bd, 1, Sk·K)
+    same, _ = gqa_tile_masks(h, kh, bs)
 
-    dims_p = (bhp, n_q, n_kv)
-    dims_d = (bd, kh, n_s)
-    n_p_tiles = int(np.prod(dims_p))
-    n_d_tiles = int(np.prod(dims_d))
-    phase = build_schedule(n_p_tiles, n_d_tiles, decode_share)
-    p_idx, d_idx = _mk_index_arrays(phase, dims_p, dims_d)
-    pbh, pqi, pki = p_idx
-    db, dh, dsi = d_idx
+    def decode_specs(ix):
+        tile = pl.BlockSpec((1, bs, kh, d), ix(
+            lambda g, pbh, pqi, pki, db, dsi, pos: (db[g], dsi[g], 0, 0)))
+        return [tile, tile,
+                pl.BlockSpec((1, 1, bs * kh), ix(
+                    lambda g, pbh, pqi, pki, db, dsi, pos:
+                    (db[g], 0, dsi[g]))),
+                pl.BlockSpec((h, bs * kh), ix(lambda *_: (0, 0)))]
 
     kernel = functools.partial(
-        _bullet_kernel,
-        bq=bq, bk=bk, bs=bs, n_kv_p=n_kv, n_s_d=n_s,
-        causal=causal, window=window,
-        scale_p=d ** -0.5, scale_d=d ** -0.5)
-
-    # Schedule arrays + pos ride in as scalar prefetch; every index_map
-    # receives them after the grid index.
-    out_p, out_d = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=8,
-            grid=(len(phase),),
-            in_specs=[
-                pl.BlockSpec((1, bq, d),
-                             lambda g, ph, pbh, pqi, pki, db, dh, dsi, pos:
-                             (pbh[g], pqi[g], 0)),
-                pl.BlockSpec((1, bk, d),
-                             lambda g, ph, pbh, pqi, pki, db, dh, dsi, pos:
-                             (pbh[g] // group, pki[g], 0)),
-                pl.BlockSpec((1, bk, d),
-                             lambda g, ph, pbh, pqi, pki, db, dh, dsi, pos:
-                             (pbh[g] // group, pki[g], 0)),
-                pl.BlockSpec((1, 1, gg, d),
-                             lambda g, ph, pbh, pqi, pki, db, dh, dsi, pos:
-                             (db[g], dh[g], 0, 0)),
-                pl.BlockSpec((1, bs, 1, d),
-                             lambda g, ph, pbh, pqi, pki, db, dh, dsi, pos:
-                             (db[g], dsi[g], dh[g], 0)),
-                pl.BlockSpec((1, bs, 1, d),
-                             lambda g, ph, pbh, pqi, pki, db, dh, dsi, pos:
-                             (db[g], dsi[g], dh[g], 0)),
-                pl.BlockSpec((1, bs),
-                             lambda g, ph, pbh, pqi, pki, db, dh, dsi, pos:
-                             (db[g], dsi[g])),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, bq, d),
-                             lambda g, ph, pbh, pqi, pki, db, dh, dsi, pos:
-                             (pbh[g], pqi[g], 0)),
-                pl.BlockSpec((1, 1, gg, d),
-                             lambda g, ph, pbh, pqi, pki, db, dh, dsi, pos:
-                             (db[g], dh[g], 0, 0)),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((bq, 1), jnp.float32),
-                pltpu.VMEM((bq, 1), jnp.float32),
-                pltpu.VMEM((bq, d), jnp.float32),
-                pltpu.VMEM((gg, 1), jnp.float32),
-                pltpu.VMEM((gg, 1), jnp.float32),
-                pltpu.VMEM((gg, d), jnp.float32),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((bhp, sp, d), qp.dtype),
-            jax.ShapeDtypeStruct((bd, kh, gg, d), qd.dtype),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(jnp.asarray(phase), jnp.asarray(pbh), jnp.asarray(pqi),
-      jnp.asarray(pki), jnp.asarray(db), jnp.asarray(dh), jnp.asarray(dsi),
-      pos.astype(jnp.int32),
-      qp, kp, vp, qd, kd, vd, kv_positions)
-    return out_p, out_d
+        _bullet_kernel, bq=bq, bk=bk, n_kv_p=sp // bk, n_s_d=n_s,
+        causal=causal, window=window, scale=d ** -0.5)
+    out_p, out_d = _fused_call(
+        kernel, [pos.astype(jnp.int32)], qp, kp, vp, qd.reshape(bd, h, d),
+        decode_specs, [kd, vd, kvpos_cols, same], n_d_tiles=bd * n_s,
+        dims_d=(bd, n_s), decode_share=decode_share, bq=bq, bk=bk,
+        group=group, interpret=interpret)
+    return out_p, out_d.reshape(bd, kh, gg, d)

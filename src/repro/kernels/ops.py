@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 These adapt model-layout tensors ((B, S, H, D) etc.) to kernel layouts, pick
-TPU-aligned block sizes, and fall back to interpret mode off-TPU (this
-container) so the same call sites work everywhere.
+TPU-aligned block sizes, and fall back to interpret mode off-TPU (the CPU
+tests) so the same call sites work everywhere.
 """
 
 from __future__ import annotations
@@ -62,7 +62,7 @@ def decode_attention_op(q, k_cache, v_cache, kv_positions, pos, *,
     kh = k_cache.shape[2]
     g = h // kh
     qr = q[:, 0].reshape(b, kh, g, d)
-    bs = _pick_block(k_cache.shape[1], 512)
+    bs = _pick_block(k_cache.shape[1], 128)
     o = _decode.decode_attention(qr, k_cache, v_cache, kv_positions, pos,
                                  block_s=bs, interpret=interpret)
     return o.reshape(b, 1, h, d)
@@ -109,7 +109,7 @@ def bullet_attention_op(qp, kp, vp, qd, kd, vd, kv_positions, pos, *,
         qpf, kpf, vpf, qdr, kd, vd, kv_positions, pos,
         decode_share=decode_share, causal=causal, window=window,
         block_q=_pick_block(sp, 128), block_k=_pick_block(sp, 128),
-        block_s=_pick_block(kd.shape[1], 512), group=g,
+        block_s=_pick_block(kd.shape[1], 128), group=g,
         interpret=interpret)
     out_p = op.reshape(bp, h, sp, d).transpose(0, 2, 1, 3)
     return out_p, od.reshape(bd, 1, h, d)

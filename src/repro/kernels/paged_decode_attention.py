@@ -10,6 +10,12 @@ only pages the table names are ever fetched. Decode HBM traffic therefore
 scales with live context (``sum_i ceil(ctx_i/ps)·ps``), not with the dense
 ``B × max_len`` capacity the slot-cache kernel streams.
 
+Each step fetches the whole page, all K KV heads (``(1, ps, K, D)``, which
+ends in the pool's own ``(K, D)`` dims as the TPU block rule requires),
+and scores all H query heads against it under the static head-match mask
+of ``decode_attention.gqa_tile_masks``. The slot positions ride in as a
+second scalar-prefetch operand.
+
 ``pages_per_seq`` is the *bucketed* max live page count across the batch:
 callers round it up (powers of two) so the grid — and hence the compiled
 executable — changes only O(log max_pages) times over a request's life.
@@ -28,46 +34,30 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from repro.kernels.decode_attention import (attend_tile, finish_softmax,
+                                            flat_tile, gqa_tile_masks,
+                                            init_softmax)
 
 
-def _paged_decode_kernel(bt_ref, q_ref, pos_ref, k_ref, v_ref, o_ref,
-                         m_ref, l_ref, acc_ref, *, ps: int, n_b: int,
-                         scale: float):
+def _paged_decode_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, same_ref,
+                         tok_ref, o_ref, m_ref, l_ref, acc_ref, *, ps: int,
+                         n_b: int, scale: float):
     del bt_ref                       # consumed by the index maps
-    i = pl.program_id(2)
+    b_ = pl.program_id(0)
+    i = pl.program_id(1)
 
     @pl.when(i == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        init_softmax(m_ref, l_ref, acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale            # (G, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)                 # (ps, D)
-    logits = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)                # (G, ps)
-    kvpos = i * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-    pos = pos_ref[0, 0]
-    valid = kvpos <= pos                                   # (1, ps)
-    logits = jnp.where(valid, logits, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, logits.max(axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(logits - m_new)
-    p = jnp.where(valid, p, 0.0)
-    l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v_ref[0, :, 0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+    kvpos = i * ps + tok_ref[...]                          # (1, ps·K)
+    valid = (same_ref[...] == 1) & (kvpos <= pos_ref[b_])  # (H, ps·K)
+    attend_tile(q_ref[0].astype(jnp.float32) * scale, flat_tile(k_ref),
+                flat_tile(v_ref), valid, m_ref, l_ref, acc_ref)
 
     @pl.when(i == n_b - 1)
     def _finalize():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = finish_softmax(l_ref, acc_ref, o_ref.dtype)
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_tables, pos, *,
@@ -78,36 +68,39 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, pos, *,
     position of the current token (−1 = inactive slot). Returns
     (B, K, G, D)."""
     b, kh, g, d = q.shape
+    h = kh * g
     ps = k_pages.shape[1]
     n_b = block_tables.shape[1]
+    same, tok = gqa_tile_masks(h, kh, ps)
 
     kernel = functools.partial(_paged_decode_kernel, ps=ps, n_b=n_b,
                                scale=d ** -0.5)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, kh, n_b),
+        num_scalar_prefetch=2,
+        grid=(b, n_b),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda b_, h, i, bt: (b_, h, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b_, h, i, bt: (b_, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, ps, 1, d), lambda b_, h, i, bt: (bt[b_, i],
-                                                              0, h, 0)),
-            pl.BlockSpec((1, ps, 1, d), lambda b_, h, i, bt: (bt[b_, i],
-                                                              0, h, 0)),
+            pl.BlockSpec((1, h, d), lambda b_, i, bt, pos: (b_, 0, 0)),
+            pl.BlockSpec((1, ps, kh, d),
+                         lambda b_, i, bt, pos: (bt[b_, i], 0, 0, 0)),
+            pl.BlockSpec((1, ps, kh, d),
+                         lambda b_, i, bt, pos: (bt[b_, i], 0, 0, 0)),
+            pl.BlockSpec((h, ps * kh), lambda b_, i, bt, pos: (0, 0)),
+            pl.BlockSpec((1, ps * kh), lambda b_, i, bt, pos: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda b_, h, i, bt: (b_, h,
-                                                                   0, 0)),
+        out_specs=pl.BlockSpec((1, h, d), lambda b_, i, bt, pos: (b_, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, d), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kh, g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(block_tables, q, pos.reshape(b, 1), k_pages, v_pages)
+    )(block_tables.astype(jnp.int32), pos.astype(jnp.int32),
+      q.reshape(b, h, d), k_pages, v_pages, same, tok)
+    return out.reshape(b, kh, g, d)

@@ -42,8 +42,6 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool,
     mem = compiled.memory_analysis()
     resident_gb = sharded_resident_gb(args, in_sh, mesh)
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):     # jax < 0.6: list of per-module dicts
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     rep = analyze_hlo(hlo)
     cfg = get_config(arch)

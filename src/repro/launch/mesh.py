@@ -8,23 +8,15 @@ tests run on the single real CPU device).
 from __future__ import annotations
 
 import jax
-
-try:                                    # jax >= 0.5
-    from jax.sharding import AxisType
-
-    def _axis_kwargs(n_axes: int):
-        return {"axis_types": (AxisType.Auto,) * n_axes}
-except ImportError:                     # older jax: Auto is the default
-    def _axis_kwargs(n_axes: int):
-        del n_axes
-        return {}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """Single-pod v5e 16×16 (256 chips) or 2-pod 2×16×16 (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -33,4 +25,4 @@ def make_host_mesh(data: int = 1, model: int = 1):
     data = min(data, n)
     model = min(model, max(n // data, 1))
     return jax.make_mesh((data, model), ("data", "model"),
-                         **_axis_kwargs(2))
+                         axis_types=(AxisType.Auto,) * 2)

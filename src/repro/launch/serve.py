@@ -28,6 +28,7 @@ Modes:
 """
 
 import argparse
+import os
 import sys
 
 
@@ -66,25 +67,35 @@ def _write_obs_outputs(args, server) -> None:
         print(f"wrote metrics snapshot to {args.metrics_out}")
 
 
-def _host(args):
+def _model(args):
+    """(cfg, params, dtype) to serve: the reduced variant in float32, or
+    with --published the published config with bf16 params and KV pool."""
     import jax
     import jax.numpy as jnp
-    import numpy as np
     from repro.configs import get_config
-    from repro.core.engine import BulletServer
     from repro.models import init_params
+
+    cfg = get_config(args.arch)
+    dtype = jnp.bfloat16 if args.published else jnp.float32
+    if not args.published:
+        cfg = cfg.reduced()
+    return cfg, init_params(cfg, jax.random.PRNGKey(0), dtype), dtype
+
+
+def _host(args):
+    import numpy as np
+    from repro.core.engine import BulletServer
     from repro.obs import Observability
     from repro.obs.report import run_report
     from repro.serving.request import Request, SLO
 
     from repro.core.config import build_server_config
 
-    cfg = get_config(args.arch).reduced()
-    params = init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    cfg, params, dtype = _model(args)
     res = _resilience_kwargs(args)
     server = BulletServer(cfg, params, config=build_server_config(
         args, slo=SLO(args.slo_ttft, args.slo_tpot), obs=Observability(),
-        faults=res.get("faults"), guard=res.get("guard")))
+        faults=res.get("faults"), guard=res.get("guard"), dtype=dtype))
     rng = np.random.default_rng(args.seed)
     reqs = []
     for rid in range(args.requests):
@@ -102,12 +113,9 @@ def _host(args):
 
 def _replay(args):
     import jax
-    import jax.numpy as jnp
-    from repro.configs import get_config
     from repro.core.engine import BulletServer
     from repro.core.estimator import HardwareSpec, PerfEstimator
     from repro.core.profiler import SurrogateMachine
-    from repro.models import init_params
     from repro.obs import Observability
     from repro.obs.report import run_report
     from repro.serving.frontend import (OnlineFrontend, VirtualClock,
@@ -116,17 +124,18 @@ def _replay(args):
     from repro.serving.request import WORKLOAD_SLOS
     from repro.serving.workload import fit_trace_to_context, generate_trace
 
-    cfg = get_config(args.arch).reduced()
-    params = init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    cfg, params, dtype = _model(args)
     # replay scores against the dataset's Table-2 SLO, same as --mode sim,
     # so the two rows are directly comparable (--slo-* applies to host mode)
     slo = WORKLOAD_SLOS[args.dataset]
-    # same hardware spec as --mode sim (the sim additionally calibrates
-    # via profiling and runs the full-size model on the unclamped trace —
-    # benchmarks/replay_vs_sim.py holds both sides identical)
+    # off the chip: the same modelled hardware as --mode sim (the sim
+    # additionally calibrates via profiling and runs the full-size model on
+    # the unclamped trace — benchmarks/replay_vs_sim.py holds both sides
+    # identical). On a TPU the engine prices the devices it runs on.
     from repro.core.config import build_server_config
 
-    est = PerfEstimator(HardwareSpec(n_chips=args.chips))
+    est = (None if jax.default_backend() == "tpu"
+           else PerfEstimator(HardwareSpec(n_chips=args.chips)))
     res = _resilience_kwargs(args)
     tenancy = None
     if args.tenants > 0:
@@ -138,7 +147,7 @@ def _replay(args):
     server = BulletServer(cfg, params, config=build_server_config(
         args, slo=slo, est=est, refit=not args.no_refit,
         obs=Observability(), tenancy=tenancy,
-        faults=res.get("faults"), guard=res.get("guard")))
+        faults=res.get("faults"), guard=res.get("guard"), dtype=dtype))
     trace = fit_trace_to_context(
         generate_trace(args.dataset, args.rate, args.duration,
                        seed=args.seed, max_requests=args.requests),
@@ -148,7 +157,8 @@ def _replay(args):
         # --oracle replays against the surrogate machine's hidden-truth
         # timings instead of the engine's own estimate: predicted-vs-actual
         # error becomes non-trivial and the OnlineRefitter closes the loop
-        cost = (oracle_cycle_cost(SurrogateMachine(est.hw, seed=args.seed))
+        cost = (oracle_cycle_cost(SurrogateMachine(server.est.hw,
+                                                   seed=args.seed))
                 if args.oracle else estimator_cycle_cost)
         fe = OnlineFrontend(server, clock, cycle_cost=cost)
     else:
@@ -270,7 +280,14 @@ def main():
                     choices=("sharegpt", "azure-code", "arxiv-summary"))
     ap.add_argument("--rate", type=float, default=40.0)
     ap.add_argument("--duration", type=float, default=30.0)
-    ap.add_argument("--chips", type=int, default=2)
+    ap.add_argument("--chips", type=int, default=2,
+                    help="chips of the modelled v5e slice (sim, "
+                         "simulate-fleet, and replay off a TPU); on a TPU "
+                         "the server prices the devices it runs on")
+    ap.add_argument("--published", action="store_true",
+                    help="host/replay: serve the published config of "
+                         "--arch with bf16 params and KV pool instead of "
+                         "the reduced float32 variant")
     ap.add_argument("--systems",
                     default="bullet,chunked-1024,chunked-2048,naive")
     ap.add_argument("--seed", type=int, default=0)
@@ -366,9 +383,14 @@ def main():
         from subprocess import run
         code = 0
         for shape in ("prefill_32k", "decode_32k"):
+            # a CPU harness (512 virtual devices): pinned to the CPU so it
+            # never contends for a chip this host may hold
             code |= run([sys.executable, "-m", "repro.launch.dryrun",
-                         "--arch", args.arch, "--shape", shape]).returncode
+                         "--arch", args.arch, "--shape", shape],
+                        env={**os.environ, "JAX_PLATFORMS": "cpu"}).returncode
         sys.exit(code)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.mode == "sim":
         args.arch = "llama3.1-8b" if args.arch == "qwen3-1.7b" else args.arch
         _sim(args)

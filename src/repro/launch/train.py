@@ -10,6 +10,7 @@ Two modes:
 """
 
 import argparse
+import os
 import sys
 import time
 
@@ -29,8 +30,11 @@ def main():
 
     if args.mode == "dryrun":
         from subprocess import run
+        # a CPU harness (512 virtual devices), pinned to the CPU so it
+        # never contends for a chip this host may hold
         sys.exit(run([sys.executable, "-m", "repro.launch.dryrun",
-                      "--arch", args.arch, "--shape", "train_4k"]).returncode)
+                      "--arch", args.arch, "--shape", "train_4k"],
+                     env={**os.environ, "JAX_PLATFORMS": "cpu"}).returncode)
 
     import jax
     import jax.numpy as jnp

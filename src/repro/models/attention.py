@@ -19,9 +19,17 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.models.sharding import shard_map as _shard_map
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
+
+
+#: the Pallas kernels' sequence block: on a TPU every prefill length is a
+#: multiple of it (the engine pads prompt batches with ``prefill_length``)
+KERNEL_BLOCK = 128
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
 
 
 def use_pallas_kernels() -> bool:
@@ -30,18 +38,46 @@ def use_pallas_kernels() -> bool:
     CPU tests exercise this path in tests/test_kernels.py)."""
     if os.environ.get("REPRO_FORCE_PALLAS") == "1":
         return True
-    return jax.default_backend() == "tpu"
+    return _on_tpu()
 
 
-def attention_prefill(q, k, v, *, causal=True, window=0, block_size=None):
+def prefill_length(n: int) -> int:
+    """The padded length of a prefill batch whose longest row has ``n``
+    tokens: on a TPU a multiple of ``KERNEL_BLOCK``, so prefill and the
+    fused cycle run the Pallas kernels; ``n`` elsewhere, so the CPU path
+    keeps its numerics reference. Padding sits after every row's last
+    real token, where causal attention never reads it."""
+    if _on_tpu():
+        return -(-n // KERNEL_BLOCK) * KERNEL_BLOCK
+    return n
+
+
+def _prefill_kernel_ok(s: int) -> bool:
+    """Whether a prefill of ``s`` tokens takes the Pallas kernels. On a
+    TPU a length they cannot tile is an error, never a quiet XLA detour;
+    off the TPU (forced interpret mode) such lengths keep the XLA path."""
+    if not use_pallas_kernels():
+        return False
+    if s % KERNEL_BLOCK and _on_tpu():
+        raise ValueError(
+            f"prefill length {s} is not a multiple of {KERNEL_BLOCK}: pad "
+            "the batch with prefill_length() before attention on a TPU")
+    return s % KERNEL_BLOCK == 0
+
+
+def attention_prefill(q, k, v, *, causal=True, window=0, block_size=None,
+                      kernels: bool = True):
     """Backend-dispatching prefill attention (model layout).
+
+    ``kernels=False`` keeps the XLA ops on every backend (the numerics
+    reference a TPU run compares its kernels with).
 
     §Perf knobs: REPRO_ATTN_BLOCK (kv block), REPRO_ATTN_BF16_PROBS
     (half-precision probabilities), REPRO_ATTN_CAUSAL_SKIP (q-chunked scan
     with a dynamic kv bound — skips fully-masked upper-triangle blocks;
     forward-only, used by the serving prefill path).
     """
-    if use_pallas_kernels() and q.shape[1] % 128 == 0:
+    if kernels and _prefill_kernel_ok(q.shape[1]):
         from repro.kernels import flash_attention_op
         return flash_attention_op(q, k, v, causal=causal, window=window)
     if block_size is None:
@@ -91,7 +127,7 @@ def attention_fused_paged(qp, kp, vp, qd, k_pages, v_pages, block_tables,
     ``attention_decode_paged``), so fused and serial engines are
     token-identical on every backend.
     """
-    if use_pallas_kernels() and qp.shape[1] % 128 == 0:
+    if _prefill_kernel_ok(qp.shape[1]):
         from repro.kernels import bullet_attention_paged_op
         return bullet_attention_paged_op(
             qp, kp, vp, qd, k_pages, v_pages, block_tables, pos,
@@ -382,7 +418,7 @@ def seq_parallel_decode_attention(q, k_cache, v_cache, kv_positions, pos, *,
         return out.transpose(0, 3, 1, 2, 4).reshape(b, sq, kh * g, dd
                                                     ).astype(q.dtype)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bax), P(bax, axis), P(bax, axis), P(bax, axis), P(bax)),
         out_specs=P(bax),
@@ -417,7 +453,7 @@ def write_cache_slot_seq_sharded(cache, new, slot, *, mesh, axis: str,
             return jnp.where(ow, written, ci)
         return jax.vmap(upd)(c, n, clamped, owns)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bax, axis), P(bax), P(bax)),
         out_specs=P(bax, axis),

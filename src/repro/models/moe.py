@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.layers import gated_mlp
-from repro.models.sharding import shard_map as _shard_map
 
 
 class MoEMetrics(NamedTuple):
@@ -61,7 +60,7 @@ def moe_ffn_sharded(x: jax.Array, params: dict, *, n_experts: int, k: int,
         return y, MoEMetrics(lb, dr)
 
     pspecs = jax.tree.map(lambda _: P(), params)
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bax, None, None), pspecs),
         out_specs=(P(bax, None, None), MoEMetrics(P(), P())),

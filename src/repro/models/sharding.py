@@ -25,24 +25,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.configs.base import ModelConfig
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False,
-              axis_names=None):
-    """jax.shard_map with a fallback onto the pre-0.6 experimental API
-    (``check_vma``/``axis_names`` translate to ``check_rep``/``auto``)."""
-    if hasattr(jax, "shard_map"):
-        kw = {"check_vma": check_vma}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    # axis_names is dropped: every mesh axis is manual (the old default) —
-    # axes unmentioned in the specs replicate, which is equivalent here and
-    # avoids partial-manual lowering old XLA:CPU cannot handle.
-    return _sm(f, mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
 @dataclass(frozen=True)
 class ShardingPolicy:
     mesh: Mesh

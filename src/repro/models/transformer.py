@@ -505,11 +505,13 @@ def _cross_attend(x, p, cfg, cross_k, cross_v, policy):
 
 
 def _apply_block_full(x, p, blk, cfg, policy, positions, cross_kv, *,
-                      window_override: Optional[int] = None):
+                      window_override: Optional[int] = None,
+                      kernels: bool = True):
     """Training/prefill block application over a full sequence.
 
     Returns (x, cache_entry, aux_loss). cache_entry holds the state a decode
-    step would need (k/v or recurrent states).
+    step would need (k/v or recurrent states). ``kernels=False`` keeps
+    attention on the XLA ops (see ``attention_prefill``).
     """
     aux = jnp.zeros((), jnp.float32)
     h = L.rms_norm(x, p["ln1"], cfg.rmsnorm_eps)
@@ -518,7 +520,8 @@ def _apply_block_full(x, p, blk, cfg, policy, positions, cross_kv, *,
         window = cfg.sliding_window if blk.mixer == SWA else 0
         if window_override is not None and blk.mixer == ATTN:
             window = window_override
-        o = attn_ops.attention_prefill(q, k, v, causal=True, window=window)
+        o = attn_ops.attention_prefill(q, k, v, causal=True, window=window,
+                                       kernels=kernels)
         y = o.reshape(*o.shape[:2], -1) @ p["wo"]
         entry = {"k": k, "v": v}
     elif blk.mixer == RGLRU:
@@ -865,8 +868,12 @@ def _cross_kv_from_encoder(p_blk, enc_out, cfg):
 
 def forward(params, tokens, cfg: ModelConfig, policy=None, *,
             frontend: Optional[jax.Array] = None,
-            remat: bool = False):
-    """Teacher-forcing forward. Returns (logits (B,S,V), aux_loss)."""
+            remat: bool = False, kernels: bool = True):
+    """Teacher-forcing forward. Returns (logits (B,S,V), aux_loss).
+
+    ``kernels=False`` runs attention on the XLA ops even on a TPU: the
+    plain reference the served path's Pallas kernels are checked against.
+    """
     enc_out = None
     if cfg.n_encoder_layers:
         assert frontend is not None
@@ -882,7 +889,7 @@ def forward(params, tokens, cfg: ModelConfig, policy=None, *,
         if cfg.cross_attention:
             cross = _cross_kv_from_encoder(p_j, enc_out, cfg)
         x, _, a = _apply_block_full(x, p_j, blk, cfg, policy,
-                                    positions, cross)
+                                    positions, cross, kernels=kernels)
         return x, a
 
     if remat:
@@ -906,7 +913,8 @@ def forward(params, tokens, cfg: ModelConfig, policy=None, *,
             cross = _cross_kv_from_encoder(params["tail_blocks"][j],
                                            enc_out, cfg)
         x, _, a = _apply_block_full(x, params["tail_blocks"][j], blk, cfg,
-                                    policy, positions, cross)
+                                    policy, positions, cross,
+                                    kernels=kernels)
         aux = aux + a
     x = L.rms_norm(x, params["final_norm"], cfg.rmsnorm_eps)
     return lm_logits(params, x, cfg, policy), aux

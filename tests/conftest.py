@@ -1,6 +1,5 @@
 import os
 import sys
-import types
 
 import pytest
 
@@ -44,91 +43,3 @@ def chip_devices():
     if len(devs) < 2:
         pytest.skip(MULTIDEVICE_HELP)
     return devs
-
-
-def _install_hypothesis_stub():
-    """Shim so test modules that use hypothesis still *collect* without it:
-    property tests skip cleanly, plain tests in the same modules run.
-    Install the real thing with ``pip install -e .[dev]``."""
-    import pytest
-
-    hyp = types.ModuleType("hypothesis")
-    st = types.ModuleType("hypothesis.strategies")
-
-    def given(*_args, **_kwargs):
-        def deco(fn):
-            def skipper():
-                pytest.skip("hypothesis not installed (pip install .[dev])")
-            skipper.__name__ = fn.__name__
-            skipper.__doc__ = fn.__doc__
-            return skipper
-        return deco
-
-    class settings:                      # noqa: N801 — mirrors hypothesis
-        def __init__(self, *args, **kwargs):
-            pass
-
-        def __call__(self, fn):
-            return fn
-
-    def _strategy(*_args, **_kwargs):
-        return None
-
-    for name in ("integers", "floats", "lists", "tuples", "sampled_from",
-                 "booleans", "text", "just", "one_of", "composite",
-                 "builds", "dictionaries"):
-        setattr(st, name, _strategy)
-    hyp.given = given
-    hyp.settings = settings
-    hyp.assume = lambda *_a, **_k: True
-    hyp.note = lambda *_a, **_k: None
-    hyp.HealthCheck = types.SimpleNamespace(all=staticmethod(lambda: []))
-    hyp.strategies = st
-
-    # hypothesis.stateful: RuleBasedStateMachine subclasses still *define*
-    # (rule/invariant/precondition decorators are pass-throughs, so the
-    # plain rule bodies stay callable by seeded fallback drivers) and
-    # their .TestCase collects as a clean skip.
-    stateful = types.ModuleType("hypothesis.stateful")
-
-    def _passthrough(*_args, **_kwargs):
-        def deco(fn):
-            return fn
-        return deco
-
-    class Bundle:                        # noqa: N801 — mirrors hypothesis
-        def __init__(self, name):
-            self.name = name
-
-    class RuleBasedStateMachine:
-        def __init_subclass__(cls, **kw):
-            super().__init_subclass__(**kw)
-            import unittest
-
-            class TestCase(unittest.TestCase):
-                def runTest(self):
-                    pytest.skip(
-                        "hypothesis not installed (pip install .[dev])")
-            TestCase.__qualname__ = cls.__name__ + ".TestCase"
-            cls.TestCase = TestCase
-
-    stateful.RuleBasedStateMachine = RuleBasedStateMachine
-    stateful.rule = _passthrough
-    stateful.invariant = _passthrough
-    stateful.initialize = _passthrough
-    stateful.precondition = _passthrough
-    stateful.Bundle = Bundle
-    stateful.consumes = lambda bundle: bundle
-    stateful.multiple = lambda *a: a
-    stateful.run_state_machine_as_test = lambda *_a, **_k: pytest.skip(
-        "hypothesis not installed (pip install .[dev])")
-    hyp.stateful = stateful
-    sys.modules["hypothesis"] = hyp
-    sys.modules["hypothesis.strategies"] = st
-    sys.modules["hypothesis.stateful"] = stateful
-
-
-try:
-    import hypothesis                    # noqa: F401
-except ImportError:
-    _install_hypothesis_stub()

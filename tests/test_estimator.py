@@ -133,3 +133,26 @@ def test_chunked_reload_increases_cost():
     t0 = est.lockstep_iter_time(CFG, [(1024, 0)], 0, 0)
     t_late = est.lockstep_iter_time(CFG, [(1024, 15 * 1024)], 0, 0)
     assert t_late > t0 * 1.05          # paper Fig. 4: later chunks slower
+
+
+# -- the served path's spec comes from the devices it holds -----------------
+
+class _Dev:
+    def __init__(self, kind):
+        self.device_kind = kind
+
+
+def test_hardware_for_prices_the_devices_in_use():
+    from repro.core.estimator import hardware_for
+    hw = hardware_for([_Dev("TPU v5 lite")] * 4)
+    assert (hw.n_chips, hw.peak_flops, hw.hbm_bw) == (4, 197e12, 819e9)
+    assert hardware_for([_Dev("TPU v5 lite")]).total_units == \
+        hw.units_per_chip
+
+
+@pytest.mark.parametrize("kinds", [["TPU v9 imaginary"],
+                                   ["TPU v5 lite", "cpu"]])
+def test_hardware_for_rejects_unknown_or_mixed_kinds(kinds):
+    from repro.core.estimator import hardware_for
+    with pytest.raises(ValueError):
+        hardware_for([_Dev(k) for k in kinds])

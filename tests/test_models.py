@@ -183,3 +183,24 @@ def test_vocab_padding_masked():
     pad = np.asarray(logits[..., cfg.vocab_size:])
     if pad.size:
         assert (pad <= -1e29).all()
+
+
+# -- prefill lengths on the chip: padded to the kernels' block, never a
+# -- quiet XLA detour (the TPU branch is steered through A._on_tpu)
+
+@pytest.mark.parametrize("n,on_tpu,want", [(1, False, 1), (300, False, 300),
+                                           (1, True, 128), (128, True, 128),
+                                           (300, True, 384)])
+def test_prefill_length_pads_only_on_tpu(monkeypatch, n, on_tpu, want):
+    monkeypatch.setattr(A, "_on_tpu", lambda: on_tpu)
+    assert A.prefill_length(n) == want
+
+
+def test_unpadded_prefill_raises_on_tpu(monkeypatch):
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    q = jnp.zeros((1, 100, 4, 32))
+    k = jnp.zeros((1, 100, 2, 32))
+    with pytest.raises(ValueError, match="multiple of 128"):
+        A.attention_prefill(q, k, k)
+    # the reference path keeps the XLA ops even on a TPU
+    assert A.attention_prefill(q, k, k, kernels=False).shape == q.shape

@@ -20,9 +20,8 @@ import sys
 sys.path.insert(0, os.environ["REPRO_SRC"])
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.configs import get_config
-from repro.launch.mesh import _axis_kwargs
 from repro.models import (init_params, init_cache, forward, prefill,
                           decode_step, param_specs, cache_specs, make_policy)
 from repro.models import transformer as T
@@ -30,9 +29,9 @@ from repro.models import transformer as T
 import os as _os
 if _os.environ.get("REPRO_TEST_MULTIPOD") == "1":
     mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
-                         **_axis_kwargs(3))
+                         axis_types=(AxisType.Auto,) * 3)
 else:
-    mesh = jax.make_mesh((2, 4), ("data", "model"), **_axis_kwargs(2))
+    mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
 def named(tree):
     return jax.tree.map(lambda s: NamedSharding(mesh, s), tree,
