@@ -55,6 +55,7 @@ from repro.launch.submesh import (HandoffPolicy, SubMeshSplit,
 from repro.models import transformer as T
 from repro.models.attention import prefill_length
 from repro.obs import NULL_OBS, CycleEvent, Observability
+from repro.obs.phases import phase
 from repro.resilience.faults import (NULL_FAULTS, DispatchError, FaultInjector,
                                      HandoffError)
 from repro.models.sharding import (submesh_cache_sharding,
@@ -300,6 +301,9 @@ class EngineStats:
     prefill_tokens: int = 0
     reused_prefill_tokens: int = 0
     prefix_hits: int = 0
+    #: device->host reads of serving (``BulletServer._to_host``): each
+    #: waits for the device to compute the array and copy it back
+    host_syncs: int = 0
 
 
 class DecodeWork(NamedTuple):
@@ -445,8 +449,14 @@ class BulletServer:
         #: failures; an attached SLOGuard installs its own
         self.handoff_policy = HandoffPolicy()
         #: the cycle event awaiting its measured duration (the driver's
-        #: record_cycle_actual completes it)
+        #: record_cycle_actual or record_cycle_duration completes it)
         self._open_cycle: Optional[CycleEvent] = None
+        #: the device arrays _to_host used last, with their host copies:
+        #: reading one of them again waits for nothing. Four cover one
+        #: step's reads (first tokens, active, pos, sampled tokens), so an
+        #: ``active`` unchanged since the last step is not read again.
+        self._host_copies: Deque[Tuple[jax.Array, np.ndarray]] = deque(
+            maxlen=4)
         if paged is None:
             paged = T.supports_paged_cache(cfg)
         elif paged and not T.supports_paged_cache(cfg):
@@ -720,6 +730,23 @@ class BulletServer:
             put = functools.partial(jax.device_put, device=sharding)
             self.cache_p = jax.tree.map(put, self.cache_p)
         task.sharding = sharding
+
+    def _to_host(self, x: jax.Array) -> np.ndarray:
+        """``x`` on the host. Every device->host read of serving goes
+        through here: the host waits until the device has computed ``x``
+        and copied it back, so each read is counted
+        (``EngineStats.host_syncs``) and spanned (``engine.readback``).
+        Reading a recently read array again is free and counts nothing."""
+        for i, (dev, host) in enumerate(self._host_copies):
+            if dev is x:
+                del self._host_copies[i]
+                self._host_copies.append((dev, host))
+                return host
+        self.stats.host_syncs += 1
+        with phase("engine.readback"):
+            out = np.asarray(x)
+        self._host_copies.append((x, out))
+        return out
 
     # -- device block tables (paged mode) -------------------------------
     def _sync_tables(self) -> None:
@@ -1095,11 +1122,14 @@ class BulletServer:
         if task is None:
             return False
         # ---- scheduling cycle between layer groups (§3.3.1) -----------
-        state = self.buffer.read()
-        decision = self.scheduler.schedule(state, now, self._pending_meta())
-        self._apply_reorder(decision.reorder)
-        self._switch(decision.resources)
-        self._launch_prefill_group(task, now)
+        with phase("engine.schedule"):
+            state = self.buffer.read()
+            decision = self.scheduler.schedule(state, now,
+                                               self._pending_meta())
+            self._apply_reorder(decision.reorder)
+            self._switch(decision.resources)
+        with phase("engine.prefill"):
+            self._launch_prefill_group(task, now)
         return True
 
     def _launch_prefill_group(self, task: PrefillTask, now: float) -> None:
@@ -1168,7 +1198,8 @@ class BulletServer:
                 self.obs.spans.mark(r.rid, "prefill_group", now,
                                     rep=float(task.rep - 1))
         if task.rep >= self.cfg.n_pattern_repeats:
-            self._finish_prefill(task, now)
+            with phase("engine.migrate"):
+                self._finish_prefill(task, now)
             self.ptask = None
 
     def _finish_prefill(self, task: PrefillTask, now: float) -> None:
@@ -1186,10 +1217,10 @@ class BulletServer:
         handoff blocks moved."""
         params = (self._params_for(task.sharding)
                   if task.sharding is not None else self.params)
-        first_tokens = np.asarray(
+        first_tokens = self._to_host(
             _final_logits(params, task.x, task.lengths, cfg=self.cfg))
         if task.granularity == "chip" and self._chip_enabled:
-            lens = np.asarray(task.lengths)
+            lens = self._to_host(task.lengths)
             live = [r for r in task.batch if r.cancel_reason is None]
             blocks: List[int] = []
             tokens_moved = 0
@@ -1499,18 +1530,20 @@ class BulletServer:
 
     # -- decode engine ----------------------------------------------------
     def _decode_cycle(self, now: float) -> bool:
-        if not bool(np.any(np.asarray(self.active))):
+        if not bool(np.any(self._to_host(self.active))):
             return False
         # ---- scheduling cycle before the iteration (§3.3.1) ------------
-        state = self.buffer.read()
-        decision = self.scheduler.schedule(state, now, self._pending_meta())
-        self._apply_reorder(decision.reorder)
-        if decision.pause_decode:
-            self.stats.paused_cycles += 1
-            self.buffer.state.decode.paused = True
-            return False
-        self.buffer.state.decode.paused = False
-        self._switch(decision.resources)
+        with phase("engine.schedule"):
+            state = self.buffer.read()
+            decision = self.scheduler.schedule(state, now,
+                                               self._pending_meta())
+            self._apply_reorder(decision.reorder)
+            if decision.pause_decode:
+                self.stats.paused_cycles += 1
+                self.buffer.state.decode.paused = True
+                return False
+            self.buffer.state.decode.paused = False
+            self._switch(decision.resources)
         if self.faults.enabled:
             self.faults.dispatch("decode")
 
@@ -1524,30 +1557,34 @@ class BulletServer:
             # task boundaries — interconnect traffic the estimator's
             # handoff charge does not cover
             params = self._params_for(self._decode_sharding)
-        act_np = np.asarray(self.active)
-        pos_np = np.asarray(self.pos)
+        act_np = self._to_host(self.active)
+        pos_np = self._to_host(self.pos)
         # live context per slot that runs this iteration — the bytes the
         # cache stream actually touches (paged) / the estimator charges
         ctxs_ran = tuple(int(p) + 1 for p, a in zip(pos_np, act_np) if a)
         n_ran = len(ctxs_ran)
         if self.paged:
-            if self._tables_dirty:
-                self._sync_tables()
-            n_b = self._decode_block_bucket(ctxs_ran)
+            with phase("engine.tables"):
+                if self._tables_dirty:
+                    self._sync_tables()
+                n_b = self._decode_block_bucket(ctxs_ran)
+                tables = self._device_tables(n_b)
             streamed = (n_b * self.page_size * self.max_slots
                         // max(n_ran, 1),) * n_ran
-            step = self._placed(_decode_iteration_impl,
-                                self._decode_sharding, _decode_iteration,
-                                donate_argnums=(1,))
-            next_tokens, self.cache = step(
-                params, self.cache, self.tokens, self.pos, self.active,
-                self._device_tables(n_b), cfg=self.cfg)
+            with phase("engine.decode"):
+                step = self._placed(_decode_iteration_impl,
+                                    self._decode_sharding, _decode_iteration,
+                                    donate_argnums=(1,))
+                next_tokens, self.cache = step(
+                    params, self.cache, self.tokens, self.pos, self.active,
+                    tables, cfg=self.cfg)
         else:
             streamed = (self.max_len * self.max_slots
                         // max(n_ran, 1),) * n_ran
-            next_tokens, self.cache = _decode_iteration(
-                params, self.cache, self.tokens, self.pos, self.active,
-                cfg=self.cfg)
+            with phase("engine.decode"):
+                next_tokens, self.cache = _decode_iteration(
+                    params, self.cache, self.tokens, self.pos, self.active,
+                    cfg=self.cfg)
         self._finish_decode_iteration(next_tokens, act_np, ctxs_ran,
                                       streamed, now)
         return True
@@ -1561,32 +1598,33 @@ class BulletServer:
         self.tokens = next_tokens
         self.pos = self.pos + act_np.astype(np.int32)
         self.stats.decode_iterations += 1
-        nt = np.asarray(next_tokens)[:, 0]
-
-        D = self.buffer.state.decode
-        for slot, r in enumerate(self.slot_req):
-            if r is None or r.phase != Phase.DECODE:
-                continue
-            tok = int(nt[slot])
-            self.outputs[r.rid].append(tok)
-            r.generated += 1
-            r.token_times.append(now)
-            D.out_tokens[r.rid] = r.generated
-            D.decode_time[r.rid] = now - (
-                r.first_token_time if r.first_token_time is not None else now)
-            if self.on_token is not None:
-                self.on_token(r, tok, now)
-            if (r.generated >= r.output_len
-                    or r.prompt_len + r.generated >= self.max_len):
-                self._finish_request(r, slot, now)
-        live = [x for x in self.slot_req
-                if x is not None and x.phase == Phase.DECODE]
-        D.batch = [x.rid for x in live]
-        D.ctx_tokens = int(sum(x.prompt_len + x.generated for x in live))
-        D.mean_context = int(D.ctx_tokens / len(live)) if live else 0
-        self.last_decode = DecodeWork(
-            n_ran, max(int(sum(ctxs_ran) / max(n_ran, 1)), 1), ctxs_ran,
-            streamed)
+        nt = self._to_host(next_tokens)[:, 0]
+        with phase("engine.emit"):
+            D = self.buffer.state.decode
+            for slot, r in enumerate(self.slot_req):
+                if r is None or r.phase != Phase.DECODE:
+                    continue
+                tok = int(nt[slot])
+                self.outputs[r.rid].append(tok)
+                r.generated += 1
+                r.token_times.append(now)
+                D.out_tokens[r.rid] = r.generated
+                D.decode_time[r.rid] = now - (
+                    r.first_token_time if r.first_token_time is not None
+                    else now)
+                if self.on_token is not None:
+                    self.on_token(r, tok, now)
+                if (r.generated >= r.output_len
+                        or r.prompt_len + r.generated >= self.max_len):
+                    self._finish_request(r, slot, now)
+            live = [x for x in self.slot_req
+                    if x is not None and x.phase == Phase.DECODE]
+            D.batch = [x.rid for x in live]
+            D.ctx_tokens = int(sum(x.prompt_len + x.generated for x in live))
+            D.mean_context = int(D.ctx_tokens / len(live)) if live else 0
+            self.last_decode = DecodeWork(
+                n_ran, max(int(sum(ctxs_ran) / max(n_ran, 1)), 1), ctxs_ran,
+                streamed)
 
     # -- fused engine (spatial co-execution, §3.5) ------------------------
     def _fused_cycle(self, now: float) -> bool:
@@ -1597,14 +1635,17 @@ class BulletServer:
         §3.3.3 pause branch still borrows the whole machine for prefill
         alone (serial group launch)."""
         task = self.ptask
-        state = self.buffer.read()
-        decision = self.scheduler.schedule(state, now, self._pending_meta())
-        self._apply_reorder(decision.reorder)
-        self._switch(decision.resources)
+        with phase("engine.schedule"):
+            state = self.buffer.read()
+            decision = self.scheduler.schedule(state, now,
+                                               self._pending_meta())
+            self._apply_reorder(decision.reorder)
+            self._switch(decision.resources)
         if decision.pause_decode:
             self.stats.paused_cycles += 1
             self.buffer.state.decode.paused = True
-            self._launch_prefill_group(task, now)
+            with phase("engine.prefill"):
+                self._launch_prefill_group(task, now)
             return True
         self.buffer.state.decode.paused = False
         ex = self.rm.executable()
@@ -1617,26 +1658,29 @@ class BulletServer:
             self._home_decode(self._global_sharding)
             self._home_task(task, self._global_sharding)
             params = self._params_for(self._global_sharding)
-        act_np = np.asarray(self.active)
-        pos_np = np.asarray(self.pos)
+        act_np = self._to_host(self.active)
+        pos_np = self._to_host(self.pos)
         ctxs_ran = tuple(int(p) + 1 for p, a in zip(pos_np, act_np) if a)
         n_ran = len(ctxs_ran)
-        if self._tables_dirty:
-            self._sync_tables()
-        n_b = self._decode_block_bucket(ctxs_ran)
+        with phase("engine.tables"):
+            if self._tables_dirty:
+                self._sync_tables()
+            n_b = self._decode_block_bucket(ctxs_ran)
+            tables = self._device_tables(n_b)
         streamed = (n_b * self.page_size * self.max_slots
                     // max(n_ran, 1),) * n_ran
-        fn = ex.fn
-        if self._chip_enabled:
-            # a chip-enabled server's tile cycles run on the global mesh
-            fn = functools.partial(
-                self._placed(_fused_step_impl, self._global_sharding,
-                             _fused_step, donate_argnums=(1,)),
-                cfg=self.cfg, decode_share=round(ex.decode_share, 6))
-        task.x, next_tokens, self.cache = fn(
-            params, self.cache, task.x, task.positions,
-            task.page_map, self.tokens, self.pos, self.active,
-            self._device_tables(n_b), rep=task.rep)
+        with phase("engine.prefill"):
+            fn = ex.fn
+            if self._chip_enabled:
+                # a chip-enabled server's tile cycles run on the global mesh
+                fn = functools.partial(
+                    self._placed(_fused_step_impl, self._global_sharding,
+                                 _fused_step, donate_argnums=(1,)),
+                    cfg=self.cfg, decode_share=round(ex.decode_share, 6))
+            task.x, next_tokens, self.cache = fn(
+                params, self.cache, task.x, task.positions,
+                task.page_map, self.tokens, self.pos, self.active,
+                tables, rep=task.rep)
         self.last_fused = True
         self.last_fused_exec = ex.config_id
         self.stats.fused_cycles += 1
@@ -1661,11 +1705,12 @@ class BulletServer:
         prefill-mesh staging pool; the finished prompt's pages re-shard
         onto the decode mesh in _finish_prefill."""
         task = self.ptask
-        state = self.buffer.read()
-        decision = self.scheduler.schedule(state, now, self._pending_meta(),
-                                           granularity="chip")
-        self._apply_reorder(decision.reorder)
-        self._switch(decision.resources)
+        with phase("engine.schedule"):
+            state = self.buffer.read()
+            decision = self.scheduler.schedule(
+                state, now, self._pending_meta(), granularity="chip")
+            self._apply_reorder(decision.reorder)
+            self._switch(decision.resources)
         ex = self.rm.executable()
         assert isinstance(ex, ChipExecutable), (
             f"chip task but executable {type(ex).__name__} for config "
@@ -1677,39 +1722,44 @@ class BulletServer:
         # group when the cycle retries at the same ``rep``.
         if self.faults.enabled:
             self.faults.dispatch("chip_prefill")
-            if bool(np.any(np.asarray(self.active))):
+            if bool(np.any(self._to_host(self.active))):
                 self.faults.dispatch("chip_decode")
-        self._home_task(task, ex.p_sharding)
-        p_params = self._params_for(ex.p_sharding)
-        rep = task.rep
-        p_slice = jax.tree.map(lambda a: a[rep], p_params["blocks"],
-                               is_leaf=lambda a: hasattr(a, "shape"))
-        task.x, kv_entries = ex.prefill_fn(p_slice, task.x, task.positions)
-        pm = task.page_map
-        rep_ix = jnp.int32(rep)
-        for j, (k_e, v_e) in enumerate(kv_entries):
-            leaf = self.cache_p["blocks"][j]
-            leaf["k"] = _scatter_group_pages(leaf["k"], k_e, pm, rep_ix)
-            leaf["v"] = _scatter_group_pages(leaf["v"], v_e, pm, rep_ix)
+        with phase("engine.prefill"):
+            self._home_task(task, ex.p_sharding)
+            p_params = self._params_for(ex.p_sharding)
+            rep = task.rep
+            p_slice = jax.tree.map(lambda a: a[rep], p_params["blocks"],
+                                   is_leaf=lambda a: hasattr(a, "shape"))
+            task.x, kv_entries = ex.prefill_fn(p_slice, task.x,
+                                               task.positions)
+            pm = task.page_map
+            rep_ix = jnp.int32(rep)
+            for j, (k_e, v_e) in enumerate(kv_entries):
+                leaf = self.cache_p["blocks"][j]
+                leaf["k"] = _scatter_group_pages(leaf["k"], k_e, pm, rep_ix)
+                leaf["v"] = _scatter_group_pages(leaf["v"], v_e, pm, rep_ix)
 
         # decode side on its own sub-mesh (when any slot is live)
-        act_np = np.asarray(self.active)
+        act_np = self._to_host(self.active)
         did_decode = bool(np.any(act_np))
         if did_decode:
             self._home_decode(ex.d_sharding)
             d_params = self._params_for(ex.d_sharding)
-            pos_np = np.asarray(self.pos)
+            pos_np = self._to_host(self.pos)
             ctxs_ran = tuple(int(p) + 1
                              for p, a in zip(pos_np, act_np) if a)
             n_ran = len(ctxs_ran)
-            if self._tables_dirty:
-                self._sync_tables()
-            n_b = self._decode_block_bucket(ctxs_ran)
+            with phase("engine.tables"):
+                if self._tables_dirty:
+                    self._sync_tables()
+                n_b = self._decode_block_bucket(ctxs_ran)
+                tables = self._device_tables(n_b)
             streamed = (n_b * self.page_size * self.max_slots
                         // max(n_ran, 1),) * n_ran
-            next_tokens, self.cache = ex.decode_fn(
-                d_params, self.cache, self.tokens, self.pos, self.active,
-                self._device_tables(n_b))
+            with phase("engine.decode"):
+                next_tokens, self.cache = ex.decode_fn(
+                    d_params, self.cache, self.tokens, self.pos,
+                    self.active, tables)
         self.last_chip = True
         self.stats.chip_cycles += 1
         if did_decode:
@@ -1767,12 +1817,19 @@ class BulletServer:
         self.pred_actual.append((obs.kind, pred, actual_s))
         if self.guard is not None:
             self.guard.on_cycle_actual(self, obs.kind, pred, actual_s)
-        if self.obs.enabled and self._open_cycle is not None:
-            self.obs.complete_cycle(self._open_cycle, actual_s)
-            self._open_cycle = None
+        self.record_cycle_duration(actual_s)
         if self.refitter is not None:
             self.refitter.observe(obs, actual_s)
             self._obs_since_refit += 1
+
+    def record_cycle_duration(self, actual_s: float) -> None:
+        """Attach the measured duration of the cycle the last step() ran
+        to its trace event (``CycleEvent.actual_s``) and nothing else: the
+        estimator, the refitter and the guard do not see it, unlike
+        :meth:`record_cycle_actual`."""
+        if self.obs.enabled and self._open_cycle is not None:
+            self.obs.complete_cycle(self._open_cycle, actual_s)
+            self._open_cycle = None
 
     def _maybe_refit(self) -> None:
         """Owned by step(): every ``refit_interval`` recorded cycles, ask
@@ -1784,14 +1841,15 @@ class BulletServer:
                 or self._obs_since_refit < self.refit_interval):
             return
         self._obs_since_refit = 0
-        new = self.refitter.refit()
-        self.stats.refits_rejected = self.refitter.refits_rejected
-        if new is not None:
-            self.est = self.est.with_params(new)
-            self.scheduler.est = self.est
-            self.refitter.est = self.est
-            self.stats.refits += 1
-            self.refit_log.append(len(self.pred_actual))
+        with phase("engine.refit"):
+            new = self.refitter.refit()
+            self.stats.refits_rejected = self.refitter.refits_rejected
+            if new is not None:
+                self.est = self.est.with_params(new)
+                self.scheduler.est = self.est
+                self.refitter.est = self.est
+                self.stats.refits += 1
+                self.refit_log.append(len(self.pred_actual))
 
     # -- observability (docs/OBSERVABILITY.md) ----------------------------
     def _record_cycle_event(self, now: float) -> None:
@@ -1837,21 +1895,23 @@ class BulletServer:
         otherwise. Returns True if any engine did work. Drive this from an
         online frontend (serving.frontend) or via :meth:`run` for offline
         batches."""
-        if self.guard is not None:
-            self.guard.before_step(self, now)
-        try:
-            did = self._step_inner(now)
-        except DispatchError as e:
-            if self.guard is None:
-                raise
-            # the cycle's work is lost but no state was mutated (every
-            # dispatch seam raises before device arrays change); the guard
-            # counts the failure and degrades once failures persist
-            self.guard.on_dispatch_failure(self, e, now)
-            did = True
-        if self.obs.enabled:
-            self._record_cycle_event(now)
-        return did
+        with phase("engine.step"):
+            if self.guard is not None:
+                self.guard.before_step(self, now)
+            try:
+                did = self._step_inner(now)
+            except DispatchError as e:
+                if self.guard is None:
+                    raise
+                # the cycle's work is lost but no state was mutated (every
+                # dispatch seam raises before device arrays change); the
+                # guard counts the failure and degrades once failures
+                # persist
+                self.guard.on_dispatch_failure(self, e, now)
+                did = True
+            if self.obs.enabled:
+                self._record_cycle_event(now)
+            return did
 
     def _step_inner(self, now: float) -> bool:
         self._maybe_refit()
@@ -1863,14 +1923,15 @@ class BulletServer:
         self.last_fused = False
         self.last_chip = False
         self.last_handoff_tokens = 0
-        did_admit = self._admit_prefill(now)
+        with phase("engine.admit"):
+            did_admit = self._admit_prefill(now)
         if self.ptask is not None and self.ptask.granularity == "chip":
             # chip-pinned task: every layer group runs on its sub-mesh,
             # with the decode iteration concurrent on the disjoint one
             return self._chip_cycle(now) or did_admit
         if (self.fused and self.ptask is not None
                 and self.ptask.prefix_map is None
-                and bool(np.any(np.asarray(self.active)))):
+                and bool(np.any(self._to_host(self.active)))):
             return self._fused_cycle(now) or did_admit
         did_p = self._prefill_step(now)
         did_d = self._decode_cycle(now)

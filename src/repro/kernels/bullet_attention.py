@@ -244,6 +244,7 @@ def _fused_call(kernel, prefetch, qp, kp, vp, qd, decode_specs,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="bullet_attention",
     )(*[jnp.asarray(a) for a in sched], *prefetch, qp, kp, vp, qd,
       *decode_inputs)
 

@@ -146,6 +146,7 @@ def decode_attention(q, k_cache, v_cache, kv_positions, pos, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="decode_attention",
     )(pos.astype(jnp.int32), q.reshape(b, h, d), k_cache, v_cache,
       kvpos_cols, same)
     return out.reshape(b, kh, g, d)

@@ -110,4 +110,5 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

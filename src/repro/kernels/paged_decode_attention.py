@@ -101,6 +101,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, pos, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_decode_attention",
     )(block_tables.astype(jnp.int32), pos.astype(jnp.int32),
       q.reshape(b, h, d), k_pages, v_pages, same, tok)
     return out.reshape(b, kh, g, d)

@@ -312,8 +312,16 @@ class OnlineFrontend:
             cycles += 1
             now = self.clock.now()
             self._release(now)
+            t_step = time.perf_counter()
             did = self.server.step(now)
-            if isinstance(self.clock, VirtualClock):
+            virtual = isinstance(self.clock, VirtualClock)
+            if not virtual and self.server.obs.enabled:
+                # the step's host time, for the cycle trace only: real
+                # durations fed to the refitter and the guard would change
+                # scheduling
+                self.server.record_cycle_duration(
+                    time.perf_counter() - t_step)
+            if virtual:
                 dt = (self.cycle_cost(self.server)
                       if self.cycle_cost else None)
                 if dt is not None and self.server.faults.enabled:

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.core.config import ServerConfig
 from repro.core.engine import BulletServer
 from repro.kvcache.paged import PagedKVPool
 from repro.models import init_params
@@ -20,7 +21,7 @@ from repro.obs import NULL_OBS, Observability
 from repro.obs.metrics import (MetricsRegistry, NULL_INSTRUMENT,
                                _NullInstrument)
 from repro.serving.frontend import (OnlineFrontend, VirtualClock,
-                                    estimator_cycle_cost)
+                                    WallClock, estimator_cycle_cost)
 from repro.serving.request import (Phase, Request, ServingMetrics, SLO,
                                    WORKLOAD_SLOS)
 from repro.serving.workload import generate_trace
@@ -297,3 +298,31 @@ def test_cycle_events_describe_the_cycle(replayed):
     decided = sum(v for k, v in snap.items()
                   if k.startswith("bullet_scheduler_decisions_total"))
     assert decided > 0
+
+
+def test_wall_clock_cycles_carry_measured_durations(setup):
+    """Under a WallClock every cycle event gets the step's measured host
+    time, and nothing of it reaches the estimator: no (predicted, actual)
+    pair is logged."""
+    cfg, params = setup
+    obs = Observability()
+    server = BulletServer(cfg, params, config=ServerConfig(
+        slo=SLO(3.0, 150.0), max_slots=4, max_len=48, obs=obs))
+    trace = generate_trace("sharegpt", rate_req_s=200.0, duration_s=10.0,
+                           seed=4, max_requests=4)
+    rng = np.random.default_rng(4)
+    fe = OnlineFrontend(server, WallClock(speed=1000.0))
+    for r in trace:
+        r.prompt_len = max(4, min(r.prompt_len, 16))
+        r.output_len = max(2, min(r.output_len, 8))
+        fe.submit(r, rng.integers(0, cfg.vocab_size, r.prompt_len,
+                                  dtype=np.int32))
+    assert fe.run().n_requests == len(trace)
+    assert len(obs.trace) > 0
+    for ev in obs.trace:
+        assert ev.actual_s is not None and ev.actual_s > 0
+    assert not server.pred_actual
+    snap = obs.registry.snapshot()
+    assert sum(v for k, v in snap.items()
+               if k.startswith("bullet_cycle_seconds_count")) == \
+        len(obs.trace)
