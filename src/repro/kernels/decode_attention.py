@@ -31,23 +31,26 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def gqa_tile_masks(h: int, kh: int, n: int):
+def gqa_tile_masks(h: int, kh: int, n: int, *, head_major: bool = False):
     """Static masks for a KV tile of ``n`` tokens flattened token-major
-    (column ``c`` = token ``c // kh``, KV head ``c % kh``).
+    (column ``c`` = token ``c // kh``, KV head ``c % kh``) or, with
+    ``head_major``, head-major (KV head ``c // n``, token ``c % n``).
 
     Returns ``same_head`` (H, n·K) int32 — 1 where query head ``r``
     (KV head ``r // (H/K)``) owns column ``c`` — and ``col_tok`` (1, n·K)
     int32, the column's token offset within the tile."""
     c = np.arange(n * kh)
-    same = (np.arange(h)[:, None] // (h // kh)) == (c[None, :] % kh)
+    head, tok = (c // n, c % n) if head_major else (c % kh, c // kh)
+    same = (np.arange(h)[:, None] // (h // kh)) == head[None, :]
     return (jnp.asarray(same.astype(np.int32)),
-            jnp.asarray((c // kh)[None, :].astype(np.int32)))
+            jnp.asarray(tok[None, :].astype(np.int32)))
 
 
 def flat_tile(ref) -> jax.Array:
-    """A ``(1, n, K, D)`` KV block as ``(n·K, D)`` float32 rows."""
-    _, n, kh, d = ref.shape
-    return ref[0].astype(jnp.float32).reshape(n * kh, d)
+    """A ``(1, n, K, D)`` (or ``(1, K, n, D)``) KV block as ``(n·K, D)``
+    float32 rows."""
+    _, a, b, d = ref.shape
+    return ref[0].astype(jnp.float32).reshape(a * b, d)
 
 
 def init_softmax(m_ref, l_ref, acc_ref) -> None:

@@ -68,19 +68,21 @@ def decode_attention_op(q, k_cache, v_cache, kv_positions, pos, *,
     return o.reshape(b, 1, h, d)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("kv_major", "interpret"))
 def paged_decode_attention_op(q, k_pages, v_pages, block_tables, pos, *,
-                              interpret=None):
-    """Model layout: q (B,1,H,D), pages (P,ps,K,D), block_tables (B,n_b)
-    int32 physical pages, pos (B,). Returns (B,1,H,D)."""
+                              kv_major=False, interpret=None):
+    """Model layout: q (B,1,H,D), pages (P,ps,K,D) — (P,K,ps,D) with
+    ``kv_major`` — block_tables (B,n_b) int32 physical pages, pos (B,).
+    Returns (B,1,H,D)."""
     if interpret is None:
         interpret = not _on_tpu()
     b, _, h, d = q.shape
-    kh = k_pages.shape[2]
+    kh = k_pages.shape[1 if kv_major else 2]
     g = h // kh
     qr = q[:, 0].reshape(b, kh, g, d)
     o = _paged.paged_decode_attention(qr, k_pages, v_pages, block_tables,
-                                      pos, interpret=interpret)
+                                      pos, kv_major=kv_major,
+                                      interpret=interpret)
     return o.reshape(b, 1, h, d)
 
 

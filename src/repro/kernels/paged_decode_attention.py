@@ -14,7 +14,10 @@ Each step fetches the whole page, all K KV heads (``(1, ps, K, D)``, which
 ends in the pool's own ``(K, D)`` dims as the TPU block rule requires),
 and scores all H query heads against it under the static head-match mask
 of ``decode_attention.gqa_tile_masks``. The slot positions ride in as a
-second scalar-prefetch operand.
+second scalar-prefetch operand. Where the TPU stores the pool with the KV
+heads major to the page's tokens (``models.attention.pool_heads_major``),
+the kernel takes the pool in that order, ``(n_pages + 1, K, page_size,
+D)``, so the pool reaches it as stored, with no relayout copy.
 
 ``pages_per_seq`` is the *bucketed* max live page count across the batch:
 callers round it up (powers of two) so the grid — and hence the compiled
@@ -61,17 +64,18 @@ def _paged_decode_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, same_ref,
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_tables, pos, *,
-                           interpret: bool = False):
-    """q: (B, K, G, D); pages: (P, ps, K, D); block_tables: (B, n_b) int32
-    physical page per (slot, block) — entries past a slot's live context
-    must point at a valid (e.g. trash) page; pos: (B,) int32 absolute
-    position of the current token (−1 = inactive slot). Returns
-    (B, K, G, D)."""
+                           kv_major: bool = False, interpret: bool = False):
+    """q: (B, K, G, D); pages: (P, ps, K, D), or (P, K, ps, D) with
+    ``kv_major``; block_tables: (B, n_b) int32 physical page per (slot,
+    block) — entries past a slot's live context must point at a valid
+    (e.g. trash) page; pos: (B,) int32 absolute position of the current
+    token (−1 = inactive slot). Returns (B, K, G, D)."""
     b, kh, g, d = q.shape
     h = kh * g
-    ps = k_pages.shape[1]
+    ps = k_pages.shape[2 if kv_major else 1]
     n_b = block_tables.shape[1]
-    same, tok = gqa_tile_masks(h, kh, ps)
+    same, tok = gqa_tile_masks(h, kh, ps, head_major=kv_major)
+    page = (1, kh, ps, d) if kv_major else (1, ps, kh, d)
 
     kernel = functools.partial(_paged_decode_kernel, ps=ps, n_b=n_b,
                                scale=d ** -0.5)
@@ -80,10 +84,8 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, pos, *,
         grid=(b, n_b),
         in_specs=[
             pl.BlockSpec((1, h, d), lambda b_, i, bt, pos: (b_, 0, 0)),
-            pl.BlockSpec((1, ps, kh, d),
-                         lambda b_, i, bt, pos: (bt[b_, i], 0, 0, 0)),
-            pl.BlockSpec((1, ps, kh, d),
-                         lambda b_, i, bt, pos: (bt[b_, i], 0, 0, 0)),
+            pl.BlockSpec(page, lambda b_, i, bt, pos: (bt[b_, i], 0, 0, 0)),
+            pl.BlockSpec(page, lambda b_, i, bt, pos: (bt[b_, i], 0, 0, 0)),
             pl.BlockSpec((h, ps * kh), lambda b_, i, bt, pos: (0, 0)),
             pl.BlockSpec((1, ps * kh), lambda b_, i, bt, pos: (0, 0)),
         ],

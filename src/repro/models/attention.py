@@ -98,19 +98,44 @@ def attention_decode(q, k_cache, v_cache, kv_positions, pos):
     return decode_attention(q, k_cache, v_cache, kv_positions, pos)
 
 
-def attention_decode_paged(q, k_pages, v_pages, block_tables, pos):
+def _tiles(n: int, bits: int) -> bool:
+    """Whether ``n`` rows fill the TPU's second-minor tiles exactly: 8 rows
+    of 32-bit words, or 1, 2 or 4 rows where a word holds no more elements
+    than that (a bf16 word holds 2)."""
+    return n % 8 == 0 or (n in (1, 2, 4) and n >= 32 // bits)
+
+
+def pool_heads_major(pages) -> bool:
+    """Whether the device stores a ``(…, ps, K, D)`` page pool with its K
+    heads major to the page's tokens. A TPU's default layout moves K out
+    of the second-minor place when K would pad its tiles and ``ps`` would
+    not (20 bf16 KV heads do; 8 do not); other backends keep the order.
+    Paged decode then works on the pool transposed to ``(…, K, ps, D)``,
+    which is how it is stored, so no relayout copy is made."""
+    if not _on_tpu():
+        return False
+    ps, kh = pages.shape[-3:-1]
+    bits = jnp.dtype(pages.dtype).itemsize * 8
+    return not _tiles(kh, bits) and _tiles(ps, bits)
+
+
+def attention_decode_paged(q, k_pages, v_pages, block_tables, pos, *,
+                           kv_major: bool = False):
     """Backend-dispatching decode attention over a block-paged cache.
 
-    q: (B, 1, H, D); pages: (P, ps, K, D) shared physical page pool;
-    block_tables: (B, n_b) int32 physical page per (slot, block) — every
-    entry must be a valid page index (unused entries point at a trash
-    page); pos: (B,) absolute position of the current token. Streams only
-    the pages the tables name, so HBM traffic scales with live context.
+    q: (B, 1, H, D); pages: (P, ps, K, D) shared physical page pool, or
+    (P, K, ps, D) with ``kv_major``; block_tables: (B, n_b) int32 physical
+    page per (slot, block) — every entry must be a valid page index
+    (unused entries point at a trash page); pos: (B,) absolute position of
+    the current token. Streams only the pages the tables name, so HBM
+    traffic scales with live context.
     """
     if use_pallas_kernels():
         from repro.kernels import paged_decode_attention_op
         return paged_decode_attention_op(q, k_pages, v_pages, block_tables,
-                                         pos)
+                                         pos, kv_major=kv_major)
+    if kv_major:
+        k_pages, v_pages = (jnp.swapaxes(a, 1, 2) for a in (k_pages, v_pages))
     return paged_decode_ref(q, k_pages, v_pages, block_tables, pos)
 
 
@@ -157,23 +182,30 @@ def paged_decode_ref(q, k_pages, v_pages, block_tables, pos):
     return decode_attention(q, kc, vc, kvpos, pos)
 
 
-def write_paged_kv(k_pages, v_pages, k_new, v_new, block_tables, pos):
+def write_paged_kv(k_pages, v_pages, k_new, v_new, block_tables, pos, *,
+                   kv_major: bool = False):
     """Write one new token's K/V into the page pool.
 
-    k_new/v_new: (B, 1, K, D); the token at absolute position ``pos[b]``
-    lands in page ``block_tables[b, pos[b] // ps]`` at offset
-    ``pos[b] % ps``. The block index is clamped to the table width so
-    slots with stale ``pos`` (inactive) write into whatever page their
-    table names there — engines point unused table entries at a trash
-    page, making those writes harmless.
+    k_new/v_new: (B, 1, K, D); pages (P, ps, K, D), or (P, K, ps, D) with
+    ``kv_major``; the token at absolute position ``pos[b]`` lands in page
+    ``block_tables[b, pos[b] // ps]`` at offset ``pos[b] % ps``. The block
+    index is clamped to the table width so slots with stale ``pos``
+    (inactive) write into whatever page their table names there — engines
+    point unused table entries at a trash page, making those writes
+    harmless.
     """
-    ps = k_pages.shape[1]
+    ps = k_pages.shape[2 if kv_major else 1]
     n_b = block_tables.shape[1]
     bi = jnp.clip(pos // ps, 0, n_b - 1)
     phys = jnp.take_along_axis(block_tables, bi[:, None], axis=1)[:, 0]
     off = jnp.clip(pos % ps, 0, ps - 1)
-    k_pages = k_pages.at[phys, off].set(k_new[:, 0].astype(k_pages.dtype))
-    v_pages = v_pages.at[phys, off].set(v_new[:, 0].astype(v_pages.dtype))
+    if kv_major:
+        heads = jnp.arange(k_pages.shape[1])[None, :]
+        at = (phys[:, None], heads, off[:, None])       # (B, K) rows of D
+    else:
+        at = (phys, off)                                # (B,) (K, D) tiles
+    k_pages = k_pages.at[at].set(k_new[:, 0].astype(k_pages.dtype))
+    v_pages = v_pages.at[at].set(v_new[:, 0].astype(v_pages.dtype))
     return k_pages, v_pages
 
 

@@ -600,20 +600,23 @@ def _kv_positions(pos, s_cache, window_like: bool):
 
 
 def _apply_block_decode(x, p, blk, cfg, policy, cache_entry, pos, cross_kv, *,
-                        long_context: bool = False, block_tables=None):
+                        long_context: bool = False, block_tables=None,
+                        kv_major: bool = False):
     """Single-token block application. x: (B,1,D). Returns (x, new_entry).
 
     With ``block_tables`` (B, n_b) the cache entry is a block-paged pool
-    (P+1, ps, K, D): the new token's K/V is scattered into its slot's
-    current page and attention gathers only the pages the table names.
+    (P+1, ps, K, D) — (P+1, K, ps, D) with ``kv_major``: the new token's
+    K/V is scattered into its slot's current page and attention gathers
+    only the pages the table names.
     """
     h = L.rms_norm(x, p["ln1"], cfg.rmsnorm_eps)
     if block_tables is not None and blk.mixer == ATTN:
         q, k_new, v_new = _project_qkv(h, p, cfg, pos[:, None], policy)
         kp, vp = attn_ops.write_paged_kv(
             cache_entry["k"], cache_entry["v"], k_new, v_new,
-            block_tables, pos)
-        o = attn_ops.attention_decode_paged(q, kp, vp, block_tables, pos)
+            block_tables, pos, kv_major=kv_major)
+        o = attn_ops.attention_decode_paged(q, kp, vp, block_tables, pos,
+                                            kv_major=kv_major)
         y = o.reshape(*o.shape[:2], -1) @ p["wo"]
         x = x + y
         y, _ = _ff(x, p, blk, cfg, policy)
@@ -991,6 +994,48 @@ def prefill(params, tokens, lengths, cache, cfg: ModelConfig, policy=None, *,
     return logits, out_cache
 
 
+def _decode_layers_paged(params, cache, x, pos, cfg: ModelConfig, policy,
+                         block_tables):
+    """The layer stack of a paged decode step. Each pattern position's pool
+    ``(R, P+1, ps, K, D)`` is viewed flat as ``(R·(P+1), ps, K, D)`` — or
+    ``(R·(P+1), K, ps, D)`` where the device stores its heads major
+    (:func:`attn_ops.pool_heads_major`) — a bitcast either way, and carried
+    through the scan, so layer ``rep``'s pages, trash page included, are
+    ``rep·(P+1) … rep·(P+1)+P``. Returns (x, cache in the stacked layout).
+    """
+    assert supports_paged_cache(cfg), cfg.pattern
+    r, n_pp, ps, kh, dh = cache["blocks"][0]["k"].shape
+    kv_major = attn_ops.pool_heads_major(cache["blocks"][0]["k"])
+
+    def flat(a):
+        a = a.reshape(r * n_pp, ps, kh, dh)
+        return jnp.swapaxes(a, 1, 2) if kv_major else a
+
+    def stacked(a):
+        a = jnp.swapaxes(a, 1, 2) if kv_major else a
+        return a.reshape(r, n_pp, ps, kh, dh)
+
+    def body(carry, xs):
+        x, pools = carry
+        p_slices, rep = xs
+        tables = block_tables + rep * n_pp
+        new_pools = []
+        for j, blk in enumerate(cfg.pattern):
+            x, entry = _apply_block_decode(x, p_slices[j], blk, cfg, policy,
+                                           pools[j], pos, None,
+                                           block_tables=tables,
+                                           kv_major=kv_major)
+            new_pools.append(entry)
+        return (x, tuple(new_pools)), None
+
+    pools = tuple({n: flat(a) for n, a in e.items()} for e in cache["blocks"])
+    (x, pools), _ = jax.lax.scan(
+        body, (x, pools),
+        (params["blocks"], jnp.arange(r, dtype=block_tables.dtype)))
+    return x, {"blocks": tuple({n: stacked(a) for n, a in e.items()}
+                               for e in pools)}
+
+
 def decode_step(params, cache, tokens, pos, cfg: ModelConfig, policy=None, *,
                 long_context: bool = False, block_tables=None):
     """One decode iteration.
@@ -998,10 +1043,18 @@ def decode_step(params, cache, tokens, pos, cfg: ModelConfig, policy=None, *,
     tokens: (B, 1) int32; pos: (B,) absolute position of the new token.
     ``block_tables`` (B, n_b) switches attention blocks to the block-paged
     cache layout of :func:`init_paged_cache` (shared across layers — every
-    layer's pool is indexed by the same table). Returns
-    (logits (B, V), new_cache).
+    layer's pool is indexed by the same table). The paged pools ride the
+    layer scan as its carry, each viewed flat as ``(R·(P+1), ps, K, D)``:
+    layer ``rep`` offsets the tables by ``rep·(P+1)``, so the token write
+    updates the donated pool in place instead of slicing each layer's pool
+    out and stacking a new one. Returns (logits (B, V), new_cache).
     """
     x = embed_tokens(params, tokens, cfg, policy)
+    if block_tables is not None:
+        x, out_cache = _decode_layers_paged(params, cache, x, pos, cfg,
+                                            policy, block_tables)
+        x = L.rms_norm(x, params["final_norm"], cfg.rmsnorm_eps)
+        return lm_logits(params, x, cfg, policy)[:, 0], out_cache
 
     def body(x, slices):
         if cfg.cross_attention:
@@ -1016,8 +1069,7 @@ def decode_step(params, cache, tokens, pos, cfg: ModelConfig, policy=None, *,
                 cross = (cross_c["k"], cross_c["v"])
             x, entry = _apply_block_decode(x, p_slices[j], blk, cfg, policy,
                                            c_slices[j], pos, cross,
-                                           long_context=long_context,
-                                           block_tables=block_tables)
+                                           long_context=long_context)
             new_entries.append(entry)
         ys = tuple(new_entries)
         if cfg.cross_attention:

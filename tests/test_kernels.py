@@ -165,6 +165,27 @@ def test_paged_decode_matches_dense(b, kh, g, n_b, ps, d):
         atol=1e-5, rtol=1e-5)
 
 
+@pytest.mark.parametrize("b,kh,g,n_b,ps,d", [
+    (2, 3, 2, 3, 16, 32), (3, 5, 1, 2, 8, 128),
+])
+def test_paged_decode_heads_major_pool(b, kh, g, n_b, ps, d):
+    """The kernel reading the pool head-major, ``(P, K, ps, D)`` (how a TPU
+    stores K heads that would pad its tiles), matches the same pool read
+    in its own order."""
+    h = kh * g
+    q = rand(jax.random.fold_in(KEY, 7), (b, 1, h, d))
+    kp, vp, bt = _mk_paged(jax.random.fold_in(KEY, 8), b, kh, d,
+                           b * n_b + 1, ps, n_b)
+    pos = jnp.asarray(
+        np.random.default_rng(2).integers(1, n_b * ps, b), jnp.int32)
+    base = paged_decode_attention_op(q, kp, vp, bt, pos, interpret=True)
+    out = paged_decode_attention_op(q, jnp.swapaxes(kp, 1, 2),
+                                    jnp.swapaxes(vp, 1, 2), bt, pos,
+                                    kv_major=True, interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(base), atol=1e-5,
+                               rtol=1e-5)
+
+
 def test_paged_decode_trash_page_isolation():
     """Entries past a slot's live context point at the trash page; its
     contents must never leak into the output (positional masking)."""

@@ -7,10 +7,15 @@ attention wrapper with ``interpret=False`` for one chip of a described
 ``v5e:2x2`` topology — no chip attached — at the published head widths
 (16 query heads, 8 KV heads, head_dim 128, bf16) and the serving smoke
 run's largest shapes. The topology is described inside a fixture, never
-at import, so every test worker collects the same tests.
+at import, so every test worker collects the same tests. The paged decode
+iteration is compiled whole, at published widths and 4 layers deep, to
+guard that it updates the page pool in place.
 """
 
+import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels import ops
+from repro.models import attention
 
 CFG = get_config("qwen3-1.7b")
 #: (prefill batch, padded prompt length), decode slots, page size, KV
@@ -85,3 +91,62 @@ def test_attention_kernel_compiles_for_v5e(name, shapes):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis() is not None
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """The program's device checks see the CPU here: tell them they are on
+    a TPU, so attention compiles the Pallas kernels for the chip."""
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_pool_heads_major_matches_v5e_layout(dtype, one_chip, on_tpu):
+    """``pool_heads_major`` says what the chip's default layout of a page
+    pool is: K heads major to the page's tokens, or the pool's own order."""
+    for kh in (1, 2, 4, 8, 12, 16, 20, 32, 40):
+        pool = jax.ShapeDtypeStruct((3, 65, PS, kh, 128), dtype,
+                                    sharding=one_chip)
+        fmt = jax.jit(lambda a: a).lower(pool).compile().input_formats
+        order = fmt[0][0].layout.major_to_minor[-3:]
+        assert order in ((2, 3, 4), (3, 2, 4)), (kh, order)
+        assert attention.pool_heads_major(pool) == (order == (3, 2, 4)), kh
+
+
+@pytest.mark.parametrize("name", ["qwen1.5-4b", "qwen3-1.7b"])
+def test_paged_decode_iteration_updates_pool_in_place(name, one_chip,
+                                                      on_tpu):
+    """The serving decode iteration at published widths (4 layers deep, 8
+    slots × 2048 tokens of 16-token pages) writes the donated page pool in
+    place: its temporaries stay under one layer stack's pool, and no copy
+    has the pool's size. Qwen1.5-4B's 20 KV heads are stored head-major."""
+    from repro.core import engine as E
+    from repro.models import init_params
+    from repro.models import transformer as T
+
+    cfg = dataclasses.replace(get_config(name), n_layers=4)
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def s(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = place(jax.eval_shape(lambda: init_params(
+        cfg, jax.random.PRNGKey(0), jnp.bfloat16)))
+    cache = place(T.init_paged_cache(cfg, B * MAX_LEN // PS, PS,
+                                     abstract=True))
+    compiled = E._decode_iteration.lower(
+        params, cache, s((B, 1)), s((B,)), s((B,), jnp.bool_),
+        s((B, MAX_LEN // PS)), cfg=cfg).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    pool = cache["blocks"][0]["k"]
+    elems = math.prod(pool.shape)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < elems * pool.dtype.itemsize, temp
+    copies = [m.group(0) for m in re.finditer(
+        r"%copy[\w.-]* = \w+\[([\d,]*)\]", compiled.as_text())
+        if math.prod(int(n) for n in m.group(1).split(",") if n) == elems]
+    assert not copies, copies
